@@ -402,6 +402,9 @@ def _main(args) -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--scenarios", default="straggler",

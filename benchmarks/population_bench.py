@@ -313,6 +313,9 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=5, help="timed steady-state rounds")
     ap.add_argument(

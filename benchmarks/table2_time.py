@@ -31,5 +31,8 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(r)

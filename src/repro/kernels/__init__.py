@@ -1,9 +1,9 @@
 """Pallas TPU kernels for FibecFed's compute hot-spots.
 
 Each kernel module pairs with an oracle in :mod:`repro.kernels.ref` and a
-jit'd public wrapper in :mod:`repro.kernels.ops`. On this CPU container the
-kernels execute under ``interpret=True`` (set ``REPRO_PALLAS_INTERPRET=0``
-on real TPU); tests sweep shapes/dtypes against the oracles.
+jit'd public wrapper in :mod:`repro.kernels.ops`. Interpret mode follows the
+platform: compiled Mosaic on a TPU backend, the Pallas interpreter on CPU
+(where the tests sweep shapes/dtypes against the oracles).
 
 Kernels:
 

@@ -22,12 +22,14 @@ SMEM row ``[thresh, scale, 0, 0]``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.masked_update import SCAL_WIDTH, _call  # noqa: F401
 from repro.kernels.masked_update import BLOCK_COLS, BLOCK_ROWS  # noqa: F401
+from repro.kernels.sparse_lora import resolve_interpret
 
 
 def _compress_kernel(
@@ -60,13 +62,14 @@ def fake_compress_2d(
     qmax: int = 0,
     use_thresh: bool = False,
     per_leaf_scale: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """One fused compress round-trip tile pass. ``x`` is (R, C)
     tile-multiple; ``scal`` is (1, SCAL_WIDTH) ``[thresh, scale, -, -]``
     (only read by the top-k / per-leaf-scale variants). Returns
     ``(y, residual)``, both ``x``-shaped and ``x``-dtyped, with
     ``y = dequant(quant(x))`` and ``residual = x - y``."""
+    interpret = resolve_interpret(interpret)
     kernel = functools.partial(
         _compress_kernel,
         qmax=qmax,

@@ -9,9 +9,13 @@ Layout: inputs are reshaped to (rows, 128-multiple cols) 2-D tiles; block
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.sparse_lora import resolve_interpret
 
 BLOCK_ROWS = 256
 BLOCK_COLS = 128
@@ -24,9 +28,11 @@ def _kernel(g_ref, fim_ref, out_ref, *, momentum: float):
 
 
 def fisher_diag_update_2d(
-    g: jax.Array, fim: jax.Array, momentum: float, *, interpret: bool = True
+    g: jax.Array, fim: jax.Array, momentum: float, *,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """g, fim: (R, C) with R % BLOCK_ROWS == 0 and C % BLOCK_COLS == 0."""
+    interpret = resolve_interpret(interpret)
     R, C = g.shape
     grid = (R // BLOCK_ROWS, C // BLOCK_COLS)
     return pl.pallas_call(

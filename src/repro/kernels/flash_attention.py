@@ -15,11 +15,14 @@ align to the MXU: q_block=128, kv_block=128, D padded to 128 multiples.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.sparse_lora import resolve_interpret
 
 NEG_INF = -1e30
 QB, KB = 128, 128
@@ -82,8 +85,9 @@ def flash_attention_bhsd(
     *,
     causal: bool = True,
     window=None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     BH, S, D = q.shape
     assert S % QB == 0 and S % KB == 0, S
     nq, nk = S // QB, S // KB

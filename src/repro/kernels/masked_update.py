@@ -31,11 +31,14 @@ parameter dtype.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.sparse_lora import resolve_interpret
 
 BLOCK_ROWS = 256
 BLOCK_COLS = 128
@@ -138,11 +141,12 @@ def masked_sgd_update_2d(
     scal: jax.Array,
     *,
     momentum: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """One fused SGD(+momentum) tile pass. All tensors (R, C) tile-multiple;
     ``mu``/``mask`` may be None; ``scal`` is (1, SCAL_WIDTH) [lr, active, -, -].
     Returns ``(new_p, new_mu)`` (``new_mu`` is None without momentum)."""
+    interpret = resolve_interpret(interpret)
     kernel = functools.partial(
         _sgd_kernel, momentum=momentum, has_mask=mask is not None
     )
@@ -165,12 +169,13 @@ def masked_adamw_update_2d(
     b2: float = 0.999,
     eps: float = 1e-8,
     wd: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """One fused AdamW tile pass. ``scal`` is (1, SCAL_WIDTH)
     [lr, active, mhat_scale, vhat_scale] (bias-correction scales are computed
     from the step counter outside the kernel). Returns (new_p, new_m, new_v).
     """
+    interpret = resolve_interpret(interpret)
     kernel = functools.partial(
         _adamw_kernel, b1=b1, b2=b2, eps=eps, wd=wd, has_mask=mask is not None
     )

@@ -1,9 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each wrapper handles padding/reshaping to kernel tile constraints and falls
-back to the oracle for shapes below one tile. Interpret mode is platform-
-aware (``kernels.sparse_lora.resolve_interpret``): ``REPRO_PALLAS_INTERPRET``
-overrides when set, else kernels interpret everywhere except real TPUs.
+back to the oracle for shapes below one tile. Interpret mode follows the
+platform (``kernels.sparse_lora.resolve_interpret``): kernels interpret
+everywhere except on a TPU backend.
 """
 from __future__ import annotations
 
@@ -25,11 +25,6 @@ from repro.kernels import ssd_chunk as _sc
 # the masked-update wrappers (padding a 64-element LoRA leaf up to a 32k tile
 # would invert the bandwidth win); use_kernel=True/False overrides per call
 MIN_KERNEL_LEAF = _mu.BLOCK_ROWS * _mu.BLOCK_COLS
-
-
-def _interpret() -> bool:
-    # platform-aware shared default: env override, else interpret off-TPU only
-    return _sl.resolve_interpret(None)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int):
@@ -55,7 +50,7 @@ def fisher_diag_update(fim, g, momentum: float = 0.9):
         padded = rows * cols
         g2 = jnp.pad(flat, (0, padded - n)).reshape(rows, cols)
         f2 = jnp.pad(f_leaf.reshape(-1), (0, padded - n)).reshape(rows, cols)
-        out = _fd.fisher_diag_update_2d(g2, f2, momentum, interpret=_interpret())
+        out = _fd.fisher_diag_update_2d(g2, f2, momentum)
         return out.reshape(-1)[:n].reshape(g_leaf.shape)
 
     return jax.tree.map(one, fim, g)
@@ -76,10 +71,10 @@ def sparse_lora_apply(x, a, b, mask, scale: float = 1.0):
         a_p, _ = _pad_to(a, 0, _sl.BK)
         b_p, _ = _pad_to(b, 1, _sl.BN)
         m_p, _ = _pad_to(mask, 0, _sl.BN)
-        y = _sl.sparse_lora_matmul(x2, a_p, b_p, m_p, scale, interpret=_interpret())
+        y = _sl.sparse_lora_matmul(x2, a_p, b_p, m_p, scale)
         y = y[:M, :N]
     else:
-        y = _sl.sparse_lora_matmul(x2, a, b, mask, scale, interpret=_interpret())
+        y = _sl.sparse_lora_matmul(x2, a, b, mask, scale)
     return y.reshape(*lead, N)
 
 
@@ -102,14 +97,10 @@ def batched_sparse_lora_apply(x, idx, a, b, mask, scale: float = 1.0):
         a_p, _ = _pad_to(a, 1, _sl.BK)
         b_p, _ = _pad_to(b, 2, _sl.BN)
         m_p, _ = _pad_to(mask, 1, _sl.BN)
-        y = _sl.batched_sparse_lora_matmul(
-            x2, idx2, a_p, b_p, m_p, scale, interpret=_interpret()
-        )
+        y = _sl.batched_sparse_lora_matmul(x2, idx2, a_p, b_p, m_p, scale)
         y = y[:M, :N]
     else:
-        y = _sl.batched_sparse_lora_matmul(
-            x2, idx2, a, b, mask, scale, interpret=_interpret()
-        )
+        y = _sl.batched_sparse_lora_matmul(x2, idx2, a, b, mask, scale)
     return y.reshape(*lead, N)
 
 
@@ -145,12 +136,10 @@ def _packed_matmul(x, a, b_packed, scale: float):
         x2, _ = _pad_to(x2, 1, _sl.BK)
         a_p, _ = _pad_to(a, 0, _sl.BK)
         b_p, _ = _pad_to(b_packed, 1, _sl.BN)
-        y = _sl.sparse_lora_matmul_packed(x2, a_p, b_p, scale, interpret=_interpret())
+        y = _sl.sparse_lora_matmul_packed(x2, a_p, b_p, scale)
         y = y[:M, :Nk]
     else:
-        y = _sl.sparse_lora_matmul_packed(
-            x2, a, b_packed, scale, interpret=_interpret()
-        )
+        y = _sl.sparse_lora_matmul_packed(x2, a, b_packed, scale)
     return y.reshape(*lead, Nk)
 
 
@@ -169,16 +158,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None):
     if S % _fa.QB:
         out = _ref.flash_attention_ref(qf, kf, vf, causal=causal, window=window)
     else:
-        out = _fa.flash_attention_bhsd(
-            qf, kf, vf, causal=causal, window=window, interpret=_interpret()
-        )
+        out = _fa.flash_attention_bhsd(qf, kf, vf, causal=causal, window=window)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
 @jax.jit
 def ssd_chunk_intra(x, a, b, c):
     """Intra-chunk SSD. x (G,Q,hd), a (G,1,Q), b/c (G,Q,N) -> (G,Q,hd) f32."""
-    return _sc.ssd_chunk_intra_kernel(x, a, b, c, interpret=_interpret())
+    return _sc.ssd_chunk_intra_kernel(x, a, b, c)
 
 
 # ---------------------------------------------------------------------------
@@ -285,7 +272,7 @@ def fake_compress(
         if _use_kernel(x.size, use_kernel):
             y2, r2 = _cp.fake_compress_2d(
                 x2, scal, qmax=qmax, use_thresh=use_thresh,
-                per_leaf_scale=per_leaf_scale, interpret=_interpret(),
+                per_leaf_scale=per_leaf_scale,
             )
         else:
             y2, r2 = _ref.fake_compress_ref(
@@ -332,7 +319,6 @@ def masked_sgd_update(
             _tile2d(mk) if mk is not None else None,
             scal,
             momentum=momentum,
-            interpret=_interpret(),
         )
         return _untile(new_p2, p), (_untile(new_mu2, mu) if momentum else None)
 
@@ -388,7 +374,6 @@ def masked_adamw_update(
             _tile2d(mk) if mk is not None else None,
             scal,
             b1=b1, b2=b2, eps=eps, wd=wd,
-            interpret=_interpret(),
         )
         return _untile(new_p2, p), _untile(new_m2, m), _untile(new_v2, v)
 

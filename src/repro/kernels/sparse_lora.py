@@ -27,7 +27,6 @@ writes out. The batched kernel adds an adapter axis: (M/bm, N/bn, A, K/bk).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -39,17 +38,13 @@ BM, BN, BK = 128, 128, 512
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Platform-aware interpret default, shared by every kernel wrapper.
+    """Platform-aware interpret default, shared by every kernel entry point.
 
-    Explicit ``True``/``False`` wins; else ``REPRO_PALLAS_INTERPRET`` (set to
-    "0"/"1") wins; else interpret everywhere EXCEPT on a real TPU backend —
-    compiled Mosaic on TPU, interpreter on CPU hosts/tests.
+    Explicit ``True``/``False`` wins; ``None`` follows the platform alone:
+    compiled Mosaic on a TPU backend, the interpreter everywhere else.
     """
     if interpret is not None:
         return interpret
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
     return jax.default_backend() != "tpu"
 
 
@@ -86,8 +81,7 @@ def sparse_lora_matmul(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Masked apply. ``interpret=None`` resolves via :func:`resolve_interpret`
-    (env override, else interpret only off-TPU) — the old always-interpret
-    default silently ran the interpreter everywhere, including real TPUs."""
+    (interpret only off-TPU)."""
     interpret = resolve_interpret(interpret)
     M, K = x.shape
     r = a.shape[1]

@@ -13,10 +13,13 @@ exponentials underflow bf16).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.sparse_lora import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -43,8 +46,9 @@ def ssd_chunk_intra_kernel(
     b: jax.Array,  # (G, Q, N)
     c: jax.Array,  # (G, Q, N)
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     G, Q, hd = x.shape
     N = b.shape[-1]
     kernel = functools.partial(_kernel, chunk=Q)

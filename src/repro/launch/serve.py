@@ -2,8 +2,8 @@
 
   python -m repro.launch.serve --arch mamba2-1.3b --batch 8 --new-tokens 16
 
-On CPU it runs the REDUCED config for real (same engine the dry-run lowers
-at production shapes).
+``--host-demo`` runs the REDUCED config instead (same engine the dry-run
+lowers at production shapes); nothing is reduced without that flag.
 """
 import argparse
 import time
@@ -18,6 +18,8 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--host-demo", action="store_true",
+                    help="run a REDUCED config for real on the local device")
     args = ap.parse_args()
 
     from repro.configs import get_config
@@ -25,7 +27,7 @@ def main():
     from repro.serve import ServeEngine, make_prompt_batch
 
     cfg = get_config(args.arch)
-    if len(jax.devices()) == 1:
+    if args.host_demo:
         cfg = cfg.reduced()
     model = build_model(cfg)
     rng = jax.random.PRNGKey(0)

@@ -1,9 +1,11 @@
 """Production training launcher.
 
-On a real TPU slice this runs the FibecFed distributed train step on the
-production mesh; on this CPU container pass ``--dry-run`` (identical code
-path to ``python -m repro.launch.dryrun``) or ``--host-demo`` to execute a
-reduced config for a few steps on the local device.
+On a TPU slice this runs the FibecFed distributed train step on the
+production mesh, which needs every one of its devices (the launcher raises,
+naming the device count, where they are missing). On a CPU host pass
+``--dry-run`` (identical code path to ``python -m repro.launch.dryrun``) or
+``--host-demo`` to execute a reduced config for a few steps on the local
+device; nothing is reduced without that flag.
 
   python -m repro.launch.train --arch qwen2-0.5b --steps 200 [--multi-pod]
 """
@@ -56,7 +58,7 @@ def main():
 
     cfg = get_config(args.arch)
     shape = get_shape(args.shape)
-    if args.host_demo or len(jax.devices()) == 1:
+    if args.host_demo:
         cfg = cfg.reduced()
         n_groups, B, S = 4, 16, 128
         mesh = None

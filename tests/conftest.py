@@ -1,5 +1,4 @@
 import os
-import pathlib
 
 # Tests run on the single real CPU device (the dry-run sets its own XLA_FLAGS
 # in-process; do NOT force 512 host devices here).
@@ -8,17 +7,12 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import pytest
 
+from repro.utils.compile_cache import enable_compile_cache
+
 # Persist XLA compilations across pytest runs: the suite is compile-bound on
 # CPU (model graphs under grad/vmap/scan), so reruns drop from minutes to
-# seconds. Best-effort — older jax without the knob just skips it.
-try:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        str(pathlib.Path(__file__).resolve().parent.parent / ".pytest_cache" / "jax"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # pragma: no cover - depends on jax version
-    pass
+# seconds.
+enable_compile_cache()
 
 
 @pytest.fixture(scope="session")

@@ -88,3 +88,13 @@ def test_spec_restrict_drops_missing_axes():
     spec = P(("pod", "data"), None, "model")
     r = shd._restrict(spec, mesh)
     assert r == P(("data",), None, "model")
+
+
+def test_production_mesh_names_missing_devices():
+    """A host that cannot form the 16x16 production mesh gets an error that
+    names its device count; the launchers no longer fall back to a reduced
+    config on their own."""
+    from repro.launch.mesh import make_production_mesh
+
+    with pytest.raises(ValueError, match=f"Number of devices {len(jax.devices())}"):
+        make_production_mesh()

@@ -49,6 +49,28 @@ def world():
     return model, make_loss_fn(model), client_data
 
 
+# SGD in this world runs on an amplified float32 noise floor. Round 1 ends
+# on a one-sample batch whose gradient norm is ~230 at lr 5e-3, so rounding
+# differences grow by three to four orders of magnitude: nudging the frozen
+# base weights by one ulp (6e-8 relative) moved the vectorized engine's
+# global LoRA by 1.5e-4 to 6.4e-4 of each leaf's largest |value| (five
+# nudges). Against a float64 referee both f32 engines sit in that band, and
+# they differ from each other by 4.2e-4: reassociation, not drift. SGD
+# comparisons therefore take an absolute tolerance of 2e-3 of the leaf's
+# scale. AdamW normalizes each step, agrees to the 1e-6 level and keeps the
+# tight tolerances.
+SGD_LEAF_TOL = 2e-3
+
+
+def _assert_lora_close(a, b, optimizer):
+    a, b = np.asarray(a), np.asarray(b)
+    if optimizer == "sgd":
+        scale = max(float(np.max(np.abs(b))), 1e-30)
+        np.testing.assert_allclose(a, b, atol=SGD_LEAF_TOL * scale, rtol=0)
+    else:
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
+
+
 def _run(world, baseline, optimizer, engine, fused=False):
     model, loss_fn, client_data = world
     runner = make_runner(
@@ -84,14 +106,12 @@ def test_engines_equivalent(world, baseline, optimizer, fused):
     gl, gv = jax.tree.leaves(r_loop.global_lora), jax.tree.leaves(r_vec.global_lora)
     assert len(gl) == len(gv)
     for a, b in zip(gl, gv):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-4)
+        _assert_lora_close(a, b, optimizer)
 
     # participating clients' host-side LoRA views track the stacked state
     for cl, cv in zip(r_loop.clients, r_vec.clients):
         for a, b in zip(jax.tree.leaves(cl.lora), jax.tree.leaves(cv.lora)):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-4
-            )
+            _assert_lora_close(a, b, optimizer)
 
 
 def test_forced_kernel_round_matches_unfused(world):
@@ -129,7 +149,11 @@ def test_reinit_after_donated_round(world):
     r_loop, s_loop = runners["loop"]
     r_vec, s_vec = runners["vectorized"]
     for cl, cv in zip(r_loop.clients, r_vec.clients):
-        np.testing.assert_allclose(cl.difficulty, cv.difficulty, rtol=1e-4)
+        # re-scored after an SGD round, so the LoRA being scored carries the
+        # amplified f32 noise floor described at SGD_LEAF_TOL: against a
+        # float64 referee the loop engine's scores are off by up to 1.4e-4
+        # and the vectorized engine's by up to 2.8e-4 (4.2e-4 apart)
+        np.testing.assert_allclose(cl.difficulty, cv.difficulty, rtol=2e-3)
         np.testing.assert_array_equal(cl.order, cv.order)
     assert s_loop["loss"] == pytest.approx(s_vec["loss"], rel=1e-4, abs=1e-5)
 

@@ -1,4 +1,4 @@
-"""Pallas kernel sweeps vs pure-jnp oracles (interpret=True on CPU)."""
+"""Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -301,24 +301,45 @@ def test_ssd_chunk_matches_model_path(rng):
 
 
 def test_resolve_interpret(monkeypatch):
-    """Explicit flag > REPRO_PALLAS_INTERPRET env > platform default.
+    """Explicit flag > platform default; no environment variable can force
+    the interpreter onto a TPU, and no entry point defaults to it."""
+    import importlib
+    import inspect
 
-    The seed hardcoded ``interpret: bool = True`` — silently running the
-    interpreter on real TPUs; the resolved default must only interpret off-TPU.
-    """
     from repro.kernels.sparse_lora import resolve_interpret
 
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    # modules by path: the package re-exports functions under the same names
+    compress, fisher_diag, flash_attention, masked_update, sparse_lora, ssd_chunk = (
+        importlib.import_module(f"repro.kernels.{m}")
+        for m in (
+            "compress", "fisher_diag", "flash_attention", "masked_update",
+            "sparse_lora", "ssd_chunk",
+        )
+    )
+
     # explicit always wins
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
     # platform default: this suite runs on CPU, so interpret
     assert jax.default_backend() != "tpu"
     assert resolve_interpret(None) is True
-    # env override
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert resolve_interpret(None) is False
+    # ... and compiled Mosaic on a TPU backend, whatever the environment says
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert resolve_interpret(None) is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert resolve_interpret(True) is True  # explicit still wins over env
+    monkeypatch.setattr(sparse_lora.jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True
+
+    # every kernel entry point leaves the choice to the platform
+    entry_points = [
+        compress.fake_compress_2d,
+        fisher_diag.fisher_diag_update_2d,
+        flash_attention.flash_attention_bhsd,
+        masked_update.masked_sgd_update_2d,
+        masked_update.masked_adamw_update_2d,
+        sparse_lora.sparse_lora_matmul,
+        sparse_lora.sparse_lora_matmul_packed,
+        sparse_lora.batched_sparse_lora_matmul,
+        ssd_chunk.ssd_chunk_intra_kernel,
+    ]
+    for fn in entry_points:
+        assert inspect.signature(fn).parameters["interpret"].default is None, fn
