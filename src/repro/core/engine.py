@@ -119,7 +119,9 @@ def make_client_step(loss_fn: Callable, opt_update: Callable) -> Callable:
         loss, grads = jax.value_and_grad(
             lambda x: masked(params, x, batch, sample_valid)
         )(lora)
-        new_lora, new_opt = opt_update(grads, opt, lora, lr, mask, active)
+        # named for the paper's optimizer, whichever update this is
+        with jax.named_scope("adamw"):
+            new_lora, new_opt = opt_update(grads, opt, lora, lr, mask, active)
         return loss, new_lora, new_opt
 
     return one_step
@@ -242,20 +244,24 @@ def _round_body(
         stacked_residual=None,
         comp_mask=None,
     ):
-        cl_lora = shard(_gather(stacked_lora, chosen))
-        cl_opt = shard(_gather(stacked_opt, chosen))
-        cl_mask = shard(_gather(neuron_mask, chosen)) if use_neuron_mask else None
-        if hoist_client_data:
-            cl_data = shard({kk: v[chosen] for kk, v in data.items()})
-            cl_sv = shard(sample_valid[chosen])
+        # named scopes (gather, merge_in, client_train, fedavg, scatter)
+        # label the program's ops in a profile; they change no number
+        with jax.named_scope("gather"):
+            cl_lora = shard(_gather(stacked_lora, chosen))
+            cl_opt = shard(_gather(stacked_opt, chosen))
+            cl_mask = shard(_gather(neuron_mask, chosen)) if use_neuron_mask else None
+            if hoist_client_data:
+                cl_data = shard({kk: v[chosen] for kk, v in data.items()})
+                cl_sv = shard(sample_valid[chosen])
 
         # line 15: overwrite the GAL part of each client's LoRA with the
         # global copy; gal_mask leaves broadcast over the client axis. The
         # float blend must not silently widen bf16 leaves.
-        cl_lora = jax.tree.map(
-            lambda g, l, m: (m * g + (1.0 - m) * l).astype(l.dtype),
-            global_lora, cl_lora, gal_mask,
-        )
+        with jax.named_scope("merge_in"):
+            cl_lora = jax.tree.map(
+                lambda g, l, m: (m * g + (1.0 - m) * l).astype(l.dtype),
+                global_lora, cl_lora, gal_mask,
+            )
 
         client_step = make_client_step(loss_fn, opt_update)
 
@@ -288,21 +294,23 @@ def _round_body(
                 )(lora_c, opt_c, batch, sv, active)
             return (lora_c, opt_c), loss
 
-        (cl_lora, cl_opt), losses = jax.lax.scan(
-            step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
-        )
+        with jax.named_scope("client_train"):
+            (cl_lora, cl_opt), losses = jax.lax.scan(
+                step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
+            )
 
         if compress is None:
             # line 18: weighted FedAvg fused over the GAL part only; with the
             # k axis sharded this contraction IS the server all-reduce (psum)
-            new_global = gal_weighted_merge(global_lora, gal_mask, cl_lora, weights)
-
-            return (
-                new_global,
-                _scatter(stacked_lora, chosen, cl_lora),
-                _scatter(stacked_opt, chosen, cl_opt),
-                losses,
-            )
+            with jax.named_scope("fedavg"):
+                new_global = gal_weighted_merge(global_lora, gal_mask, cl_lora, weights)
+            with jax.named_scope("scatter"):
+                return (
+                    new_global,
+                    _scatter(stacked_lora, chosen, cl_lora),
+                    _scatter(stacked_opt, chosen, cl_opt),
+                    losses,
+                )
 
         # compressed upload: each client ships the dequantized reconstruction
         # of its GAL delta (+ carried residual); the server applies the
@@ -311,13 +319,6 @@ def _round_body(
         from repro.kernels import ops as _kops
 
         ef = compress["error_feedback"]
-        delta = jax.tree.map(
-            lambda l, g, m: (l - g) * m, cl_lora, global_lora, gal_mask
-        )
-        cl_res = shard(_gather(stacked_residual, chosen)) if ef else None
-        cl_cm = (
-            shard(_gather(comp_mask, chosen)) if compress["has_comp_mask"] else None
-        )
 
         def one(d, r, cm):
             return _kops.fake_compress(
@@ -327,19 +328,28 @@ def _round_body(
                 use_thresh=compress["use_thresh"],
             )
 
-        y, new_res = jax.vmap(
-            one,
-            in_axes=(0, 0 if ef else None, 0 if cl_cm is not None else None),
-        )(delta, cl_res, cl_cm)
-        new_global = gal_delta_merge(global_lora, gal_mask, y, weights)
+        with jax.named_scope("fedavg"):
+            delta = jax.tree.map(
+                lambda l, g, m: (l - g) * m, cl_lora, global_lora, gal_mask
+            )
+            cl_res = shard(_gather(stacked_residual, chosen)) if ef else None
+            cl_cm = (
+                shard(_gather(comp_mask, chosen)) if compress["has_comp_mask"] else None
+            )
+            y, new_res = jax.vmap(
+                one,
+                in_axes=(0, 0 if ef else None, 0 if cl_cm is not None else None),
+            )(delta, cl_res, cl_cm)
+            new_global = gal_delta_merge(global_lora, gal_mask, y, weights)
 
-        return (
-            new_global,
-            _scatter(stacked_lora, chosen, cl_lora),
-            _scatter(stacked_opt, chosen, cl_opt),
-            losses,
-            _scatter(stacked_residual, chosen, new_res) if ef else stacked_residual,
-        )
+        with jax.named_scope("scatter"):
+            return (
+                new_global,
+                _scatter(stacked_lora, chosen, cl_lora),
+                _scatter(stacked_opt, chosen, cl_opt),
+                losses,
+                _scatter(stacked_residual, chosen, new_res) if ef else stacked_residual,
+            )
 
     return round_fn
 
@@ -415,11 +425,13 @@ def _cohort_round_body(
         comp_mask=None,
     ):
         # line 15: overwrite the GAL part of each client's LoRA with the
-        # global copy (dtype-preserving, gal_mask broadcast over k)
-        cl_lora = jax.tree.map(
-            lambda g, l, m: (m * g + (1.0 - m) * l).astype(l.dtype),
-            global_lora, cohort_lora, gal_mask,
-        )
+        # global copy (dtype-preserving, gal_mask broadcast over k); the
+        # named scopes are _round_body's, less gather and scatter
+        with jax.named_scope("merge_in"):
+            cl_lora = jax.tree.map(
+                lambda g, l, m: (m * g + (1.0 - m) * l).astype(l.dtype),
+                global_lora, cohort_lora, gal_mask,
+            )
         cl_opt = cohort_opt
         cl_mask = neuron_mask if use_neuron_mask else None
 
@@ -443,12 +455,14 @@ def _cohort_round_body(
                 )(lora_c, opt_c, batch, sv, active)
             return (lora_c, opt_c), loss
 
-        (cl_lora, cl_opt), losses = jax.lax.scan(
-            step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
-        )
+        with jax.named_scope("client_train"):
+            (cl_lora, cl_opt), losses = jax.lax.scan(
+                step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
+            )
 
         if compress is None:
-            new_global = gal_weighted_merge(global_lora, gal_mask, cl_lora, weights)
+            with jax.named_scope("fedavg"):
+                new_global = gal_weighted_merge(global_lora, gal_mask, cl_lora, weights)
             return new_global, cl_lora, cl_opt, losses
 
         # compressed upload: same fake-quantize/top-k round trip as the
@@ -456,9 +470,6 @@ def _cohort_round_body(
         from repro.kernels import ops as _kops
 
         ef = compress["error_feedback"]
-        delta = jax.tree.map(
-            lambda l, g, m: (l - g) * m, cl_lora, global_lora, gal_mask
-        )
 
         def one(d, r, cm):
             return _kops.fake_compress(
@@ -468,15 +479,19 @@ def _cohort_round_body(
                 use_thresh=compress["use_thresh"],
             )
 
-        y, new_res = jax.vmap(
-            one,
-            in_axes=(
-                0,
-                0 if ef else None,
-                0 if compress["has_comp_mask"] else None,
-            ),
-        )(delta, cohort_residual if ef else None, comp_mask if compress["has_comp_mask"] else None)
-        new_global = gal_delta_merge(global_lora, gal_mask, y, weights)
+        with jax.named_scope("fedavg"):
+            delta = jax.tree.map(
+                lambda l, g, m: (l - g) * m, cl_lora, global_lora, gal_mask
+            )
+            y, new_res = jax.vmap(
+                one,
+                in_axes=(
+                    0,
+                    0 if ef else None,
+                    0 if compress["has_comp_mask"] else None,
+                ),
+            )(delta, cohort_residual if ef else None, comp_mask if compress["has_comp_mask"] else None)
+            new_global = gal_delta_merge(global_lora, gal_mask, y, weights)
         return (
             new_global,
             cl_lora,
@@ -687,6 +702,7 @@ def _difficulty_body(loss_fn: Callable, metric: str) -> Callable:
     else:
         raise ValueError(f"no vectorized difficulty path for metric {metric!r}")
 
+    @jax.named_scope("difficulty_grads")
     def diff(params, stacked_lora, data, sample_valid):
         # lora is vmapped alongside the data: clients start from identical
         # copies, but a re-init after training must score each client's own
@@ -738,6 +754,7 @@ def _fim_warmup_body(loss_fn: Callable, momentum: float) -> Callable:
         (fim, _), _ = jax.lax.scan(body, (zero, jnp.ones((), bool)), (cdata, csv))
         return fim
 
+    @jax.named_scope("fim_warmup_program")
     def warm(params, stacked_lora, wdata, wsv):
         return jax.vmap(lambda lo, cd, cv: per_client(params, lo, cd, cv))(
             stacked_lora, wdata, wsv

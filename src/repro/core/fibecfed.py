@@ -53,7 +53,9 @@ ascending | descending), ``sparse_update`` on/off.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence
+import time
+from contextlib import contextmanager
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +107,17 @@ def _memo(key, build):
         runtime_metrics.counter("jit.program_builds").inc()
         _PROGRAM_MEMO[key] = build()
     return _PROGRAM_MEMO[key]
+
+
+@contextmanager
+def _round_phase(tel, name: str) -> Iterator[None]:
+    """A host phase of a stacked-engine round: the span ``round_<name>`` on
+    track ``server`` (an annotation alone with telemetry off), and its
+    seconds in the runtime histogram ``fl.round_<name>_s`` either way."""
+    start = time.perf_counter()
+    with tel.span(f"round_{name}", cat="fl", track="server"):
+        yield
+    runtime_metrics.histogram(f"fl.round_{name}_s").observe(time.perf_counter() - start)
 
 
 def clear_compile_caches() -> None:
@@ -588,6 +601,7 @@ class FibecFed:
         logits_loss = make_logits_loss(cfg)
 
         def build():
+            @jax.named_scope("sensitivity_probe")
             def fn(params, lora, batch):
                 B, T = batch["tokens"].shape
                 S = T + (cfg.num_prefix_embeddings if cfg.family == "vlm" else 0)
@@ -775,12 +789,12 @@ class FibecFed:
         if self._stacked_engine and not self._oocore and metric in ("fisher", "loss"):
             # one program over every (client, batch) cell, each client scored
             # with its own LoRA (matters on re-init after training rounds)
-            scores = np.asarray(
-                self._difficulty_fn()(
-                    self.params, self._stacked_lora, self._stack_data,
-                    self._sample_valid,
-                )
+            scores = self._difficulty_fn()(
+                self.params, self._stacked_lora, self._stack_data,
+                self._sample_valid,
             )
+            with self.tel.span("difficulty_read", cat="fl", track="server"):
+                scores = np.asarray(scores)
             for ci, client in enumerate(self.clients):
                 client.difficulty = scores[ci, : len(client.batches)]
                 client.order = curr.order_batches(
@@ -793,53 +807,61 @@ class FibecFed:
 
     def _select_local_masks(self) -> None:
         """Lines 8-10: momentum-FIM warmup → per-client neuron keep-masks."""
-        fl = self.fl
+        fl, span = self.fl, self.tel.span
         if self._stacked_engine and not self._oocore:
             C = len(self.clients)
             C_stack = self._sample_valid.shape[0]  # includes mesh padding rows
-            warm_idx = np.zeros((C_stack, fl.fim_warmup_epochs), np.int64)
-            for ci, c in enumerate(self.clients):
-                warm_idx[ci] = [
-                    int(c.order[min(e, len(c.order) - 1)])
-                    for e in range(fl.fim_warmup_epochs)
-                ]
-            rows = jnp.arange(C_stack)[:, None]
-            cols = jnp.asarray(warm_idx)
-            wdata = {k: v[rows, cols] for k, v in self._stack_data.items()}
-            wsv = self._sample_valid[rows, cols]
-            if self.mesh is not None:
-                # the eager gather above leaves committed replicated arrays;
-                # the sharded warmup program wants them client-sharded
-                client_shd = eng.client_sharding(self.mesh)
-                wdata = jax.device_put(wdata, client_shd)
-                wsv = jax.device_put(wsv, client_shd)
-            fims = self._fim_warmup_fn()(self.params, self._stacked_lora, wdata, wsv)
-            importance = sparsemod.neuron_importance(fims)  # leaves (C, L, d_out)
-            if fl.sparse_ratio is not None:
-                keep = sparsemod.select_neuron_masks(importance, fl.sparse_ratio)
-                self._stacked_mask = jax.vmap(
-                    lambda kp: neuron_mask_tree(self.cfg, self._init_lora, kp)
-                )(keep)
-            else:  # per-client lossless ρ: build masks client by client
-                per_client = []
-                for ci, client in enumerate(self.clients):
-                    imp_ci = jax.tree.map(lambda x: x[ci], importance)
-                    keep = sparsemod.select_neuron_masks(
-                        imp_ci, client.lossless_fraction
+            with span("fim_gather", cat="fl", track="server"):
+                warm_idx = np.zeros((C_stack, fl.fim_warmup_epochs), np.int64)
+                for ci, c in enumerate(self.clients):
+                    warm_idx[ci] = [
+                        int(c.order[min(e, len(c.order) - 1)])
+                        for e in range(fl.fim_warmup_epochs)
+                    ]
+                rows = jnp.arange(C_stack)[:, None]
+                cols = jnp.asarray(warm_idx)
+                wdata = {k: v[rows, cols] for k, v in self._stack_data.items()}
+                wsv = self._sample_valid[rows, cols]
+                if self.mesh is not None:
+                    # the eager gather above leaves committed replicated arrays;
+                    # the sharded warmup program wants them client-sharded
+                    client_shd = eng.client_sharding(self.mesh)
+                    wdata = jax.device_put(wdata, client_shd)
+                    wsv = jax.device_put(wsv, client_shd)
+            with span("fim_program", cat="fl", track="server"):
+                fims = self._fim_warmup_fn()(self.params, self._stacked_lora, wdata, wsv)
+            with span("fim_select", cat="fl", track="server"):
+                importance = sparsemod.neuron_importance(fims)  # leaves (C, L, d_out)
+                if fl.sparse_ratio is not None:
+                    keep = sparsemod.select_neuron_masks(importance, fl.sparse_ratio)
+                    self._stacked_mask = jax.vmap(
+                        lambda kp: neuron_mask_tree(self.cfg, self._init_lora, kp)
+                    )(keep)
+                else:  # per-client lossless ρ: build masks client by client
+                    per_client = []
+                    for ci, client in enumerate(self.clients):
+                        imp_ci = jax.tree.map(lambda x: x[ci], importance)
+                        keep = sparsemod.select_neuron_masks(
+                            imp_ci, client.lossless_fraction
+                        )
+                        per_client.append(
+                            neuron_mask_tree(self.cfg, self._init_lora, keep)
+                        )
+                    # padding rows are never trained; any finite mask will do
+                    per_client += [per_client[0]] * (C_stack - C)
+                    self._stacked_mask = jax.tree.map(
+                        lambda *xs: jnp.stack(xs), *per_client
                     )
-                    per_client.append(neuron_mask_tree(self.cfg, self._init_lora, keep))
-                # padding rows are never trained; any finite mask will do
-                per_client += [per_client[0]] * (C_stack - C)
-                self._stacked_mask = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *per_client
-                )
-            if self.mesh is not None:
-                self._stacked_mask = jax.device_put(
-                    self._stacked_mask, eng.client_sharding(self.mesh)
-                )
-            for ci, client in enumerate(self.clients):
-                client.fim = jax.tree.map(lambda x: x[ci], fims)
-                client.neuron_mask = jax.tree.map(lambda x: x[ci], self._stacked_mask)
+                if self.mesh is not None:
+                    self._stacked_mask = jax.device_put(
+                        self._stacked_mask, eng.client_sharding(self.mesh)
+                    )
+            with span("fim_slice", cat="fl", track="server"):
+                for ci, client in enumerate(self.clients):
+                    client.fim = jax.tree.map(lambda x: x[ci], fims)
+                    client.neuron_mask = jax.tree.map(
+                        lambda x: x[ci], self._stacked_mask
+                    )
             return
         for ci, client in enumerate(self.clients):
             fim = None
@@ -946,31 +968,33 @@ class FibecFed:
         """Per-client layer-sensitivity probe (Eq. 9-10) + lossless-fraction
         estimation, aggregated server-side (Eq. 11). Returns
         ``(global_scores, fractions, ns)``."""
-        sensitivity = self._sensitivity_fn()
+        sensitivity, span = self._sensitivity_fn(), self.tel.span
         layer_scores_all, fractions, ns = [], [], []
         for ci, client in enumerate(self.clients):
-            ids = client.batches[int(client.order[0])]
-            batch = self._client_batch(client, ids)
-            scores = sensitivity(self.params, client.lora, batch)
-            client.layer_scores = np.asarray(scores)
-            layer_scores_all.append(client.layer_scores)
-            ns.append(client.n)
+            with span("sensitivity_client", cat="fl", track="server", args={"ci": ci}):
+                ids = client.batches[int(client.order[0])]
+                batch = self._client_batch(client, ids)
+                scores = sensitivity(self.params, client.lora, batch)
+                with span("sensitivity_read", cat="fl", track="server"):
+                    client.layer_scores = np.asarray(scores)
+                layer_scores_all.append(client.layer_scores)
+                ns.append(client.n)
 
-            # --- lossless fraction (only if not overridden; costly) ---
-            if fl.gal_fraction is None or fl.sparse_ratio is None:
-                client.lossless_fraction = galmod.lossless_rank_fraction(
-                    self.loss_fn,
-                    self.params,
-                    client.lora,
-                    batch,
-                    jax.random.fold_in(self.key, 1000 + ci),
-                    iters=fl.lanczos_iters,
+                # --- lossless fraction (only if not overridden; costly) ---
+                if fl.gal_fraction is None or fl.sparse_ratio is None:
+                    client.lossless_fraction = galmod.lossless_rank_fraction(
+                        self.loss_fn,
+                        self.params,
+                        client.lora,
+                        batch,
+                        jax.random.fold_in(self.key, 1000 + ci),
+                        iters=fl.lanczos_iters,
+                    )
+                fractions.append(
+                    client.lossless_fraction
+                    if fl.gal_fraction is None
+                    else fl.gal_fraction
                 )
-            fractions.append(
-                client.lossless_fraction
-                if fl.gal_fraction is None
-                else fl.gal_fraction
-            )
         return galmod.aggregate_layer_scores(layer_scores_all, ns), fractions, ns
 
     def init_phase(self, *, probe_batches: int = 1) -> None:
@@ -1156,7 +1180,8 @@ class FibecFed:
 
     def run_round(self, t: int, lr: Optional[float] = None) -> Dict[str, float]:
         if not self.tel.enabled:
-            return self._dispatch_round(t, lr)
+            with self.tel.span("round"):  # the profiler annotation alone
+                return self._dispatch_round(t, lr)
         tel = self.tel
         start = tel.tracer.now()
         with tel.span(
@@ -1170,11 +1195,6 @@ class FibecFed:
         m = tel.metrics
         m.counter("fl.rounds").inc()
         m.histogram("fl.round_s").observe(dur)
-        if dur > 0.0:
-            m.gauge("fl.rounds_per_s").set(1.0 / dur)
-        loss = stats.get("loss")
-        if loss is not None and not np.isnan(loss):
-            m.histogram("fl.round_loss").observe(loss)
         if self.comm_bytes_per_round:
             m.counter("fl.comm_bytes").inc(self.comm_bytes_per_round[-1])
             m.counter("fl.comm_upload_bytes").inc(
@@ -1269,93 +1289,96 @@ class FibecFed:
     def _run_round_vectorized(
         self, t: int, lr: Optional[float] = None
     ) -> Dict[str, float]:
-        fl = self.fl
+        fl, tel = self.fl, self.tel
         lr = fl.learning_rate if lr is None else lr
-        k = min(fl.devices_per_round, len(self.clients))
-        chosen = self.rng.choice(len(self.clients), k, replace=False)
-        orders = [self.clients[ci].order for ci in chosen]
-        batch_idx, step_valid = curr.step_plan(
-            self.schedule, t, orders, fl.local_epochs
-        )
-        w = np.asarray([self.clients[ci].n for ci in chosen], np.float64)
-        w = (w / w.sum()).astype(np.float32)
+        with _round_phase(tel, "plan"):
+            k = min(fl.devices_per_round, len(self.clients))
+            chosen = self.rng.choice(len(self.clients), k, replace=False)
+            orders = [self.clients[ci].order for ci in chosen]
+            batch_idx, step_valid = curr.step_plan(
+                self.schedule, t, orders, fl.local_epochs
+            )
+            w = np.asarray([self.clients[ci].n for ci in chosen], np.float64)
+            w = (w / w.sum()).astype(np.float32)
 
-        if self._cohort_pad > k:
-            # sharded engine: pad the cohort onto the stack's inert padding
-            # rows (distinct indices keep the scatter free of duplicate
-            # writes; zero weight and zero valid steps make them no-ops)
-            pad_n = self._cohort_pad - k
-            pad_rows = np.arange(len(self.clients), len(self.clients) + pad_n)
-            chosen = np.concatenate([chosen, pad_rows])
-            batch_idx = np.pad(batch_idx, ((0, pad_n), (0, 0)))
-            step_valid = np.pad(step_valid, ((0, pad_n), (0, 0)))
-            w = np.pad(w, (0, pad_n))
+            if self._cohort_pad > k:
+                # sharded engine: pad the cohort onto the stack's inert padding
+                # rows (distinct indices keep the scatter free of duplicate
+                # writes; zero weight and zero valid steps make them no-ops)
+                pad_n = self._cohort_pad - k
+                pad_rows = np.arange(len(self.clients), len(self.clients) + pad_n)
+                chosen = np.concatenate([chosen, pad_rows])
+                batch_idx = np.pad(batch_idx, ((0, pad_n), (0, 0)))
+                step_valid = np.pad(step_valid, ((0, pad_n), (0, 0)))
+                w = np.pad(w, (0, pad_n))
 
-        round_fn = self._round_fn()
-        mask_arg = (
-            self._stacked_mask if self._stacked_mask is not None else jnp.zeros(())
-        )
-        args = (
-            self.params,
-            self.global_lora,
-            self._stacked_lora,
-            self._stacked_opt,
-            mask_arg,
-            self._gal_mask_tree,
-            self._stack_data,
-            self._sample_valid,
-            jnp.asarray(chosen, jnp.int32),
-            jnp.asarray(batch_idx),
-            jnp.asarray(step_valid),
-            jnp.asarray(w),
-            jnp.float32(lr),
-        )
-        if self.compression is None:
-            self.global_lora, self._stacked_lora, self._stacked_opt, losses = (
-                round_fn(*args)
+        with _round_phase(tel, "put"):
+            mask_arg = (
+                self._stacked_mask if self._stacked_mask is not None else jnp.zeros(())
             )
-        else:
-            res_arg = (
-                self._stacked_residual
-                if self.compression.error_feedback
-                else jnp.zeros(())
-            )
-            cm_arg = (
-                self._stacked_comp_mask
-                if self._stacked_comp_mask is not None
-                else jnp.zeros(())
-            )
-            (
+            args = (
+                self.params,
                 self.global_lora,
                 self._stacked_lora,
                 self._stacked_opt,
-                losses,
-                new_res,
-            ) = round_fn(*args, res_arg, cm_arg)
-            if self.compression.error_feedback:
-                self._stacked_residual = new_res
+                mask_arg,
+                self._gal_mask_tree,
+                self._stack_data,
+                self._sample_valid,
+                jnp.asarray(chosen, jnp.int32),
+                jnp.asarray(batch_idx),
+                jnp.asarray(step_valid),
+                jnp.asarray(w),
+                jnp.float32(lr),
+            )
+            if self.compression is not None:
+                res_arg = (
+                    self._stacked_residual
+                    if self.compression.error_feedback
+                    else jnp.zeros(())
+                )
+                cm_arg = (
+                    self._stacked_comp_mask
+                    if self._stacked_comp_mask is not None
+                    else jnp.zeros(())
+                )
+                args += (res_arg, cm_arg)
+        with _round_phase(tel, "dispatch"):
+            round_fn = self._round_fn()
+            if self.compression is None:
+                self.global_lora, self._stacked_lora, self._stacked_opt, losses = (
+                    round_fn(*args)
+                )
+            else:
+                (
+                    self.global_lora,
+                    self._stacked_lora,
+                    self._stacked_opt,
+                    losses,
+                    new_res,
+                ) = round_fn(*args)
+                if self.compression.error_feedback:
+                    self._stacked_residual = new_res
 
-        losses = np.asarray(losses)  # (S, k)
-        valid = step_valid.T
-        mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
+        with _round_phase(tel, "wait"):
+            losses = np.asarray(losses)  # (S, k)
+        with _round_phase(tel, "account"):
+            valid = step_valid.T
+            mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
 
-        self.last_round_info = {
-            "chosen": np.asarray(chosen[:k]),
-            "client_steps": step_valid[:k].sum(axis=1).astype(np.int64),
-        }
-        total, up = self._gal_bytes(chosen[:k])
-        self.comm_bytes_per_round.append(total)
-        self.comm_upload_bytes_per_round.append(up)
+            self.last_round_info = {
+                "chosen": np.asarray(chosen[:k]),
+                "client_steps": step_valid[:k].sum(axis=1).astype(np.int64),
+            }
+            total, up = self._gal_bytes(chosen[:k])
+            self.comm_bytes_per_round.append(total)
+            self.comm_upload_bytes_per_round.append(up)
+            selected = np.mean(
+                [len(curr.selected_batch_ids(self.schedule, t, o)) for o in orders]
+            )
         return {
             "loss": mean_loss,
-            "selected_batches": float(
-                np.mean(
-                    [
-                        len(curr.selected_batch_ids(self.schedule, t, o))
-                        for o in orders
-                    ]
-                )
-            ),
+            "selected_batches": float(selected),
             "comm_bytes": float(self.comm_bytes_per_round[-1]),
             # compiled step-shape of this round (pow2-bucketed): the
             # curriculum-bucketing test asserts few distinct values per ramp

@@ -196,20 +196,27 @@ def decoder_layer(
     cache=None, cache_position=None, ring=False, causal=True,
     sample_weight=None,
 ):
-    """One transformer block. Returns (h, aux_loss, new_cache)."""
-    x = _norm(h, p, "attn_norm", cfg.norm)
-    attn_out, new_cache = attention_sublayer(
-        x, p, lora, cfg, positions, lora_scale=lora_scale, causal=causal,
-        cache=cache, cache_position=cache_position, ring=ring,
-    )
+    """One transformer block. Returns (h, aux_loss, new_cache).
+
+    Its ops carry the named scopes ``attention`` and ``mlp`` (norm and
+    residual add included), which label them in a profile."""
+    with jax.named_scope("attention"):
+        x = _norm(h, p, "attn_norm", cfg.norm)
+        attn_out, new_cache = attention_sublayer(
+            x, p, lora, cfg, positions, lora_scale=lora_scale, causal=causal,
+            cache=cache, cache_position=cache_position, ring=ring,
+        )
     if cfg.parallel_residual:
-        mlp_out, aux = _ffn(x, p, cfg, lora, lora_scale, sample_weight)
-        h = h + attn_out + mlp_out
+        with jax.named_scope("mlp"):
+            mlp_out, aux = _ffn(x, p, cfg, lora, lora_scale, sample_weight)
+            h = h + attn_out + mlp_out
     else:
-        h = h + attn_out
-        x2 = _norm(h, p, "mlp_norm", cfg.norm)
-        mlp_out, aux = _ffn(x2, p, cfg, lora, lora_scale, sample_weight)
-        h = h + mlp_out
+        with jax.named_scope("attention"):
+            h = h + attn_out
+        with jax.named_scope("mlp"):
+            x2 = _norm(h, p, "mlp_norm", cfg.norm)
+            mlp_out, aux = _ffn(x2, p, cfg, lora, lora_scale, sample_weight)
+            h = h + mlp_out
     return h, aux, new_cache
 
 
@@ -227,6 +234,7 @@ def _embed_inputs(params, tokens, cfg: ModelConfig, prefix_embeds=None):
     return h
 
 
+@jax.named_scope("lm_head")
 def _lm_logits(h, params, cfg: ModelConfig):
     if cfg.norm == "layernorm":
         h = layer_norm(h, params["final_norm_w"], params["final_norm_b"])

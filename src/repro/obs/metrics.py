@@ -15,7 +15,8 @@ byte counts, staleness, latencies, and token counts alike.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
+from collections import deque
+from typing import Deque, Dict, Optional
 
 __all__ = [
     "Counter",
@@ -64,9 +65,16 @@ def _bucket_exponent(v: float) -> str:
 
 
 class Histogram:
-    """Power-of-two-bucketed distribution with count/sum/min/max."""
+    """Power-of-two-bucketed distribution with count/sum/min/max.
 
-    __slots__ = ("count", "total", "vmin", "vmax", "buckets")
+    ``recent`` keeps the last :attr:`RECENT` observations in order, so a
+    reader can take exactly the last *n* (the rounds of a measured window);
+    ``snapshot()`` leaves it out.
+    """
+
+    __slots__ = ("count", "total", "vmin", "vmax", "buckets", "recent")
+
+    RECENT = 1024
 
     def __init__(self) -> None:
         self.count = 0
@@ -74,9 +82,11 @@ class Histogram:
         self.vmin = math.inf
         self.vmax = -math.inf
         self.buckets: Dict[str, int] = {}
+        self.recent: Deque[float] = deque(maxlen=self.RECENT)
 
     def observe(self, v: float) -> None:
         v = float(v)
+        self.recent.append(v)
         self.count += 1
         self.total += v
         if v < self.vmin:
@@ -195,7 +205,10 @@ class NullRegistry:
 
 # Process-wide registry for runtime-level signals that are not tied to one
 # runner/engine instance — jitted-program builds through the compile memo
-# (``core.fibecfed._memo``) and cache clears.  Always live (the counters are
-# a handful of float adds per *compile*, never per step), so retrace
-# accounting works even for runs constructed without a Telemetry object.
+# (``core.fibecfed._memo``), cache clears, and the seconds of each host phase
+# of a stacked-engine round (``fl.round_{plan,put,dispatch,wait,account}_s``).
+# Always live, so retrace and round-phase accounting work even for runs
+# constructed without a Telemetry object: a handful of float adds per
+# *compile*, and six clock reads and five appends per *round* (rounds take
+# seconds), never per step.
 runtime_metrics = MetricsRegistry()

@@ -11,12 +11,22 @@ Engines take ``telemetry=None`` and normalize via :func:`ensure`:
 
 ``NullTelemetry`` makes the disabled path bit-identical and near-free: its
 tracer never reads the clock, its metrics are a shared do-nothing object,
-and ``span()`` is a no-op context manager — no branches on values, no
+and ``span()`` is the bare profiler annotation — no branches on values, no
 device sync, no allocation beyond the context-manager frame.
+
+An enabled ``Telemetry`` also records every garbage collection of the
+process as a ``gc`` span on track ``gc`` (``generation`` and ``collected``
+in its args), so that a host pause shows under its own name; ``close()``
+stops that.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Union
+import gc
+import threading
+import weakref
+from typing import Any, Callable, Dict, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 from .exporters import write_jsonl, write_perfetto
 from .metrics import MetricsRegistry, NullRegistry, runtime_metrics
@@ -35,6 +45,33 @@ class Telemetry:
         self.meta: Dict[str, Any] = dict(meta) if meta else {}
         self.tracer = Tracer()
         self.metrics = MetricsRegistry()
+        # thread -> (start, annotation) of the collection in progress there
+        self._gc_open: Dict[int, Tuple[float, TraceAnnotation]] = {}
+        hook = _gc_hook(weakref.ref(self))
+        gc.callbacks.append(hook)
+        # the hook holds this object weakly; it goes with it, or on close()
+        self._gc_unhook = weakref.finalize(self, _remove_gc_hook, hook)
+
+    def close(self) -> None:
+        """Stop recording garbage collections. Idempotent."""
+        self._gc_unhook()
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        tid = threading.get_ident()
+        if phase == "start":
+            ann = TraceAnnotation("gc")
+            ann.__enter__()
+            self._gc_open[tid] = (self.tracer.now(), ann)
+            return
+        opened = self._gc_open.pop(tid, None)
+        if opened is None:  # hooked in while this collection ran
+            return
+        start, ann = opened
+        ann.__exit__(None, None, None)
+        self.tracer.add_span(
+            "gc", start=start, end=self.tracer.now(), cat="gc", track="gc",
+            args={"generation": info["generation"], "collected": info["collected"]},
+        )
 
     # convenience passthroughs so call sites read `tel.span(...)`
     def span(self, name: str, **kw: Any):
@@ -73,7 +110,7 @@ class NullTelemetry:
     tracer: NullTracer = NULL_TRACER
     metrics = NullRegistry()
 
-    span = _null_span
+    span = staticmethod(_null_span)
 
     def instant(self, name: str, **_kw: Any) -> None:
         pass
@@ -89,6 +126,19 @@ class NullTelemetry:
 
 
 NULL_TELEMETRY = NullTelemetry()
+
+
+def _gc_hook(ref: "weakref.ref[Telemetry]") -> Callable[[str, Dict[str, int]], None]:
+    def hook(phase: str, info: Dict[str, int]) -> None:
+        tel = ref()
+        if tel is not None:
+            tel._on_gc(phase, info)
+
+    return hook
+
+
+def _remove_gc_hook(hook) -> None:
+    gc.callbacks.remove(hook)
 
 
 def ensure(telemetry: Union[Telemetry, NullTelemetry, None]):

@@ -15,6 +15,12 @@ Every event carries a ``track`` (a timeline row: ``"host"``, ``"server"``,
 ``(clock, track)`` row must nest or be disjoint, never partially overlap —
 is checked by :func:`check_spans` and enforced in tests.
 
+Every live span (``span()``, and ``NullTracer.span``) also opens a
+``jax.profiler.TraceAnnotation`` under its own name, so that a profiler
+trace taken around the run holds the program's phases on the host plane,
+on the same clock as the device's ops. With no profiler running an
+annotation costs a flag check.
+
 All recording is host-side Python appending to a list; nothing here touches
 jax values or forces device sync.
 """
@@ -23,6 +29,8 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "NULL_TRACER",
@@ -110,7 +118,8 @@ class Tracer:
         track: str = "host",
         args: Optional[Dict[str, Any]] = None,
     ) -> Iterator[Dict[str, Any]]:
-        """Live wall-clock span around a host-side block.
+        """Live wall-clock span around a host-side block, annotated for the
+        profiler under ``name``.
 
         Yields the span's ``args`` dict so the body can attach results
         (loss, byte counts, step counts) before the span closes.
@@ -118,7 +127,8 @@ class Tracer:
         span_args: Dict[str, Any] = dict(args) if args else {}
         start = self.now()
         try:
-            yield span_args
+            with TraceAnnotation(name):
+                yield span_args
         finally:
             self.add_span(
                 name,
@@ -132,12 +142,15 @@ class Tracer:
 
 
 @contextmanager
-def _null_span(*_a: Any, **_k: Any) -> Iterator[Dict[str, Any]]:
-    yield {}
+def _null_span(name: str, **_k: Any) -> Iterator[Dict[str, Any]]:
+    """The profiler annotation alone: records nothing, reads no clock."""
+    with TraceAnnotation(name):
+        yield {}
 
 
 class NullTracer:
-    """No-op tracer: records nothing, never reads the clock."""
+    """No-op tracer: records nothing, never reads the clock; its spans are
+    bare profiler annotations."""
 
     __slots__ = ()
 
@@ -153,7 +166,7 @@ class NullTracer:
     def instant(self, name: str, **_kw: Any) -> None:
         pass
 
-    span = _null_span
+    span = staticmethod(_null_span)
 
 
 NULL_TRACER = NullTracer()

@@ -62,29 +62,31 @@ def make_loss_fn(model: ModelFns) -> Callable:
 
     def loss_fn(params, lora, batch: Dict[str, Any]):
         logits, aux = model.forward(params, lora, batch)
-        if cfg.family == "encoder":
-            return cls_loss(logits, batch["labels"]) + aux
-        if "label_token" in batch:
-            return label_token_loss(logits, batch["label_token"]) + aux
-        offset = cfg.num_prefix_embeddings if cfg.family == "vlm" else 0
-        return lm_loss(logits, batch["tokens"], offset) + aux
+        with jax.named_scope("loss"):
+            if cfg.family == "encoder":
+                return cls_loss(logits, batch["labels"]) + aux
+            if "label_token" in batch:
+                return label_token_loss(logits, batch["label_token"]) + aux
+            offset = cfg.num_prefix_embeddings if cfg.family == "vlm" else 0
+            return lm_loss(logits, batch["tokens"], offset) + aux
 
     def masked(params, lora, batch: Dict[str, Any], sample_mask):
         if cfg.family == "moe":
             batch = dict(batch, sample_mask=sample_mask)
         logits, aux = model.forward(params, lora, batch)
-        m = sample_mask.astype(jnp.float32)
-        denom = jnp.maximum(jnp.sum(m), 1.0)
-        if cfg.family == "encoder":
-            per = _xent(logits, batch["labels"])
-        elif "label_token" in batch:
-            per = _xent(logits[:, -1], batch["label_token"])
-        else:
-            offset = cfg.num_prefix_embeddings if cfg.family == "vlm" else 0
-            tokens = batch["tokens"]
-            pred = logits[:, offset : offset + tokens.shape[1] - 1]
-            per = jnp.mean(_xent(pred, tokens[:, 1:]), axis=-1)
-        return jnp.sum(per * m) / denom + aux
+        with jax.named_scope("loss"):
+            m = sample_mask.astype(jnp.float32)
+            denom = jnp.maximum(jnp.sum(m), 1.0)
+            if cfg.family == "encoder":
+                per = _xent(logits, batch["labels"])
+            elif "label_token" in batch:
+                per = _xent(logits[:, -1], batch["label_token"])
+            else:
+                offset = cfg.num_prefix_embeddings if cfg.family == "vlm" else 0
+                tokens = batch["tokens"]
+                pred = logits[:, offset : offset + tokens.shape[1] - 1]
+                per = jnp.mean(_xent(pred, tokens[:, 1:]), axis=-1)
+            return jnp.sum(per * m) / denom + aux
 
     loss_fn.masked = masked
     # hold the model ref so id() stays unique for the cache's lifetime

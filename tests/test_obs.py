@@ -396,6 +396,188 @@ def test_init_phase_spans_nest_under_init(world):
     assert np.isfinite(rd["args"]["loss"]) and rd["args"]["t"] == 0
 
 
+ROUND_PHASES = ("plan", "put", "dispatch", "wait", "account")
+INIT_SUB_SPANS = {
+    "difficulty": ("difficulty_read",),
+    "sensitivity": ("sensitivity_client", "sensitivity_read"),
+    "fim_warmup": ("fim_gather", "fim_program", "fim_select", "fim_slice"),
+}
+
+
+def _spans(tel, name):
+    return [e for e in tel.tracer.events if e["type"] == "span" and e["name"] == name]
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-9)
+
+
+@pytest.mark.parametrize("engine,kw", [("vectorized", {}), ("sharded", {"mesh": "1"})])
+def test_round_phase_spans_nest_under_round(world, engine, kw):
+    kw = {"mesh": make_client_mesh(1)} if kw else {}
+    tel = Telemetry()
+    _run_fl(world, engine, telemetry=tel, **kw)
+    check_spans(tel.tracer.events)
+    rounds = _spans(tel, "round")
+    assert len(rounds) == ROUNDS
+    for p in ROUND_PHASES:
+        phase = _spans(tel, f"round_{p}")
+        assert len(phase) == ROUNDS
+        for ph, rd in zip(phase, rounds):
+            assert ph["track"] == rd["track"] == "server"
+            assert _inside(ph, rd)
+
+
+def test_round_phase_spans_sum_to_at_most_the_round(world):
+    tel = Telemetry()
+    _run_fl(world, "vectorized", telemetry=tel)
+    phases = [_spans(tel, f"round_{p}") for p in ROUND_PHASES]
+    for i, rd in enumerate(_spans(tel, "round")):
+        total = sum(ph[i]["dur"] for ph in phases)
+        assert 0.0 < total <= rd["dur"]
+        # in order, without overlap
+        starts = [ph[i]["ts"] for ph in phases]
+        assert starts == sorted(starts)
+
+
+def test_round_phase_histograms_count_every_round_with_telemetry_off(world):
+    hists = [runtime_metrics.histogram(f"fl.round_{p}_s") for p in ROUND_PHASES]
+    runner, _ = _run_fl(world, "vectorized", telemetry=None, rounds=0)
+    for t in range(3):
+        before = [(h.count, len(h.recent)) for h in hists]
+        runner.run_round(t)
+        for h, (count, n) in zip(hists, before):
+            assert h.count == count + 1
+            assert len(h.recent) == min(n + 1, h.RECENT)
+            assert h.recent[-1] >= 0.0
+    assert runtime_metrics.snapshot()["histograms"]["fl.round_wait_s"]["count"] == hists[3].count
+
+
+def test_histogram_recent_keeps_the_last_observations():
+    h = MetricsRegistry().histogram("h")
+    for v in range(h.RECENT + 10):
+        h.observe(v)
+    assert len(h.recent) == h.RECENT == 1024
+    assert list(h.recent)[-3:] == [h.RECENT + 7, h.RECENT + 8, h.RECENT + 9]
+    assert h.recent[0] == 10.0
+    assert h.count == h.RECENT + 10
+    assert "recent" not in h.snapshot()
+
+
+def test_init_sub_spans_nest_under_their_phases(world):
+    tel = Telemetry()
+    runner, _ = _run_fl(world, "vectorized", telemetry=tel, rounds=0)
+    runner.init_phase()
+    check_spans(tel.tracer.events)
+    inits = _spans(tel, "init_phase")
+    assert len(inits) == 2
+    for phase, subs in INIT_SUB_SPANS.items():
+        outer = _spans(tel, phase)
+        assert len(outer) == len(inits)  # once per init_phase
+        assert all(_inside(o, i) for o, i in zip(outer, inits))
+        for sub in subs:
+            inner = _spans(tel, sub)
+            assert inner and all(any(_inside(e, o) for o in outer) for e in inner)
+    clients = _spans(tel, "sensitivity_client")
+    assert [e["args"]["ci"] for e in clients] == list(range(FL.num_devices)) * 2
+    for rd in _spans(tel, "sensitivity_read"):
+        assert sum(_inside(rd, c) for c in clients) == 1
+
+
+def test_gc_collections_recorded_until_close():
+    import gc
+
+    tel = Telemetry()
+    gc.collect()
+    (ev,) = _spans(tel, "gc")
+    assert ev["track"] == "gc" and ev["clock"] == WALL
+    assert ev["args"]["generation"] == 2 and ev["args"]["collected"] >= 0
+    tel.close()
+    tel.close()  # idempotent
+    gc.collect()
+    assert len(_spans(tel, "gc")) == 1
+
+
+def test_gc_hook_goes_with_its_telemetry():
+    import gc
+
+    n = len(gc.callbacks)
+    tel = Telemetry()
+    assert len(gc.callbacks) == n + 1
+    del tel
+    gc.collect()
+    assert len(gc.callbacks) == n
+
+
+def _scope_names(lowered) -> set:
+    """Every name in the op locations of a lowered program, with JAX's
+    transform wrappers (``vmap(jvp(lm_head))``) taken off."""
+    import re
+
+    names = set()
+    for loc in re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)):
+        for part in loc.split("/"):
+            names.add(re.sub(r"^(?:\w+\()+|\)+$", "", part))
+    return names
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(world):
+    import jax.numpy as jnp
+
+    runner, _ = _run_fl(world, "vectorized", rounds=0)
+    r = runner
+    kp, S, E = r._cohort_pad, 2, FL.fim_warmup_epochs
+    round_args = (
+        r.params, r.global_lora, r._stacked_lora, r._stacked_opt, r._stacked_mask,
+        r._gal_mask_tree, r._stack_data, r._sample_valid,
+        jnp.arange(kp, dtype=jnp.int32), jnp.zeros((kp, S), jnp.int32),
+        jnp.ones((kp, S), jnp.float32), jnp.full((kp,), 1.0 / kp, jnp.float32),
+        jnp.float32(FL.learning_rate),
+    )
+    wdata = {k: v[:, :E] for k, v in r._stack_data.items()}
+    client = r.clients[0]
+    return {
+        "round": r._round_fn().lower(*round_args),
+        "difficulty": r._difficulty_fn().lower(
+            r.params, r._stacked_lora, r._stack_data, r._sample_valid),
+        "fim": r._fim_warmup_fn().lower(
+            r.params, r._stacked_lora, wdata, r._sample_valid[:, :E]),
+        "sensitivity": r._sensitivity_fn().lower(
+            r.params, client.lora, r._client_batch(client, client.batches[0])),
+    }
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("round", ("gather", "merge_in", "client_train", "attention", "mlp", "lm_head",
+               "loss", "adamw", "fedavg", "scatter")),
+    ("difficulty", ("difficulty_grads", "attention", "mlp", "lm_head", "loss")),
+    ("fim", ("fim_warmup_program", "lm_head", "loss")),
+    ("sensitivity", ("sensitivity_probe", "attention")),
+])
+def test_programs_name_their_scopes(lowered_programs, program, scopes):
+    names = _scope_names(lowered_programs[program])
+    assert set(scopes) <= names, sorted(set(scopes) - names)
+
+
+def test_profiler_trace_holds_round_phases(world, tmp_path):
+    """With telemetry off, a profiler trace around a round holds the round
+    and its phases on the host plane, the clock of the device's ops."""
+    import glob
+
+    runner, _ = _run_fl(world, "vectorized", telemetry=None, rounds=1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        runner.run_round(1)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert {"round"} | {f"round_{p}" for p in ROUND_PHASES} <= host
+
+
 def test_async_straggler_trace_reconciles_with_comm_accounting(world, tmp_path):
     """The acceptance contract: a straggler async run's virtual-clock spans
     must reconcile EXACTLY with the runner's own accounting — upload-span
