@@ -101,7 +101,11 @@ def step_plan(
     full curriculum ramp from ``beta * NB`` to ``NB`` batches retraces the
     jitted round program at most ``log2(S_max) + 1`` times instead of once
     per distinct count — the padding steps are masked no-ops, so engine
-    equivalence is unaffected.
+    equivalence is unaffected. The single-device engine trains the valid
+    steps in fewer lanes than clients where :func:`pack_lanes` fits them;
+    its lane count depends on ``S`` alone (:func:`lane_count`), so a ramp
+    compiles at most ``log2(S_max) + 1`` packed programs besides the
+    unpacked ones.
 
     ``max_selected`` (optional, one entry per client, ``None`` entries =
     uncapped) caps each client's per-epoch selected count — the async
@@ -130,3 +134,87 @@ def step_plan(
             batch_idx[i, lo : lo + len(sel)] = sel
             step_valid[i, lo : lo + len(sel)] = 1.0
     return batch_idx, step_valid
+
+
+def _first_fit_decreasing(counts, S: int, max_lanes: Optional[int] = None):
+    """First-fit-decreasing placement of runs of ``counts[i]`` steps into
+    lanes of ``S`` steps: ``(lane, offset)`` per run, and the lanes used.
+    ``None`` where a run is longer than ``S`` or needs lane ``max_lanes``."""
+    lanes, place = [], [None] * len(counts)
+    # longest first; ties keep the cohort's order (a stable sort)
+    for i in sorted(range(len(counts)), key=lambda i: -counts[i]):
+        c = counts[i]
+        if c > S:
+            return None
+        for lane, used in enumerate(lanes):
+            if used + c <= S:
+                break
+        else:
+            lane = len(lanes)
+            if max_lanes is not None and lane >= max_lanes:
+                return None
+            lanes.append(0)
+        place[i] = (lane, lanes[lane])
+        lanes[lane] += c
+    return place, len(lanes)
+
+
+def lane_count(
+    schedule: CurriculumSchedule, n_batches, k: int, S: int, local_epochs: int = 1
+) -> int:
+    """Lanes a packed round at scan length ``S`` gets: over every round of
+    ``schedule``, the largest first-fit-decreasing lane count of the ``k``
+    largest per-client step counts of the whole population (``n_batches``
+    per client, ``local_epochs`` each) that fit in ``S``.
+
+    It depends on ``S`` and the population alone, not on the round or the
+    cohort drawn, so a job compiles at most one packed program per step
+    bucket. A cohort that does not fit (first-fit-decreasing is not
+    monotone) takes the unpacked program.
+    """
+    # the fraction only grows, and stays put once the ramp is done
+    last = max(schedule.total_rounds, math.ceil(schedule.alpha * schedule.total_rounds))
+    lanes, seen = 0, set()
+    for t in range(last + 1):
+        counts = tuple(local_epochs * num_selected_batches(schedule, t, n) for n in n_batches)
+        if counts in seen:
+            continue
+        seen.add(counts)
+        fits = sorted((c for c in counts if c <= S), reverse=True)
+        lanes = max(lanes, _first_fit_decreasing(fits[:k], S)[1])
+    return lanes
+
+
+def pack_lanes(client_steps, S: int, L: int):
+    """Pack a cohort's ragged step sequences into ``L`` lanes of ``S`` steps.
+
+    ``client_steps[i]`` is chosen client ``i``'s batch ids in the order it
+    trains them (:func:`step_plan`'s valid steps of row ``i``: epoch-major,
+    each epoch the curriculum's selected batches). First-fit-decreasing puts
+    each client's run whole into one lane at an offset, so a lane trains its
+    clients one after another. Returns ``(lane_client (L, S) int32,
+    batch_idx (L, S) int32, step_valid (L, S) f32)``: at step ``s`` lane
+    ``l`` trains cohort row ``lane_client[l, s]`` on batch
+    ``batch_idx[l, s]`` iff ``step_valid[l, s]``. A lane's steps after its
+    last client stay inactive on that client's row; an empty lane points at
+    the scratch row ``k + l`` (``k`` the cohort size), so no two lanes name
+    one row at a step. ``None`` where the cohort does not fit.
+    """
+    k = len(client_steps)
+    counts = [len(s) for s in client_steps]
+    packed = _first_fit_decreasing(counts, S, L)
+    if packed is None:
+        return None
+    place, _ = packed
+    lane_client = np.repeat(np.arange(k, k + L, dtype=np.int32)[:, None], S, axis=1)
+    batch_idx = np.zeros((L, S), np.int32)
+    step_valid = np.zeros((L, S), np.float32)
+    # by offset: each client's row runs from its first step to the lane's
+    # end, until the lane's next client takes over
+    for i in sorted(range(k), key=lambda i: place[i][1]):
+        lane, lo = place[i]
+        hi = lo + counts[i]
+        lane_client[lane, lo:] = i
+        batch_idx[lane, lo:hi] = client_steps[i]
+        step_valid[lane, lo:hi] = 1.0
+    return lane_client, batch_idx, step_valid

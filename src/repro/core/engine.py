@@ -34,6 +34,14 @@ the mesh's client-group count (``stack_clients(pad_clients_to=...)``); the
 runner also pads the chosen cohort with dedicated padding rows (zero weight,
 zero valid steps) so gather/scatter never write one row twice.
 
+Lane packing (single device only): where the cohort's shards are skewed,
+:func:`build_packed_round_fn` trains the cohort in fewer ``vmap`` lanes than
+clients, each lane running several clients' curriculum steps back to back
+(:func:`repro.core.curriculum.pack_lanes`), so a short client no longer pads
+to the longest. The sharded engine keeps one lane per client (its lanes are
+the mesh's client axis), and the out-of-core cohort program and the async
+engine's per-client program are unchanged.
+
 The round program decomposes into two separately-callable pieces shared by
 every engine: :func:`make_client_step` (one masked local step) and
 :func:`gal_weighted_merge` (the fused weighted GAL FedAvg). The async engine
@@ -74,8 +82,10 @@ def _gather(tree, idx):
     return jax.tree.map(lambda x: x[idx], tree)
 
 
-def _scatter(tree, idx, values):
-    return jax.tree.map(lambda s, c: s.at[idx].set(c), tree, values)
+def _scatter(tree, idx, values, unique=False):
+    return jax.tree.map(
+        lambda s, c: s.at[idx].set(c, unique_indices=unique), tree, values
+    )
 
 
 def client_sharding(mesh) -> NamedSharding:
@@ -206,6 +216,7 @@ def _round_body(
     shard: Callable = lambda t: t,
     hoist_client_data: bool = False,
     compress: Any = None,
+    packed: bool = False,
 ) -> Callable:
     """The round program shared by the single-device and sharded engines.
 
@@ -225,25 +236,96 @@ def _round_body(
     normalized weights sum to one. The round program then takes two extra
     trailing arguments (the stacked residual state and an optional per-client
     top-k count mask) and returns the updated residuals as a fifth output.
-    """
 
-    def round_fn(
-        params,
-        global_lora,
-        stacked_lora,
-        stacked_opt,
-        neuron_mask,
-        gal_mask,
-        data: Dict[str, Any],
-        sample_valid,
-        chosen,
-        batch_idx,
-        step_valid,
-        weights,
-        lr,
-        stacked_residual=None,
-        comp_mask=None,
-    ):
+    ``packed`` (single device only) trains the cohort in the lanes of a
+    :func:`repro.core.curriculum.pack_lanes` plan: the program takes
+    ``lane_client`` (L, S) after ``chosen``, ``batch_idx``/``step_valid`` are
+    (L, S), and the losses come back (S, L). See :func:`build_packed_round_fn`.
+    """
+    client_step = make_client_step(loss_fn, opt_update)
+
+    def lanes_step(params, lr, lora_c, opt_c, mask_c, batch, sv, active):
+        # one curriculum step of every lane: the client step vmapped over
+        # the leading lane axis
+        def one_step(lo, op, mk, b, m, a):
+            return client_step(params, lo, op, mk, b, m, lr, a)
+
+        if use_neuron_mask:
+            return jax.vmap(one_step)(lora_c, opt_c, mask_c, batch, sv, active)
+        return jax.vmap(lambda lo, op, b, m, a: one_step(lo, op, None, b, m, a))(
+            lora_c, opt_c, batch, sv, active
+        )
+
+    def train_cohort(params, lr, data, sample_valid, chosen, cl_lora, cl_opt, cl_mask,
+                     batch_idx, step_valid):
+        # one lane per chosen client, every lane as long as the longest; with
+        # hoist_client_data, data and sample_valid are the cohort's own grid
+        def step(carry, xs):
+            lora_c, opt_c = carry
+            bidx, active = xs  # (k,), (k,)
+            if hoist_client_data:
+                # per-client batch pick stays aligned on the k axis (no
+                # cross-device gather inside the scan)
+                batch = shard(
+                    {kk: jax.vmap(lambda d, j: d[j])(v, bidx) for kk, v in data.items()}
+                )
+                sv = shard(jax.vmap(lambda d, j: d[j])(sample_valid, bidx))
+            else:
+                batch = {kk: v[chosen, bidx] for kk, v in data.items()}
+                sv = sample_valid[chosen, bidx]
+            # padded steps compute but do not commit: the optimizer's
+            # ``active`` predicate holds LoRA, moments, and Adam's step
+            # counter in the same pass (exactly like the loop engine)
+            loss, lora_c, opt_c = lanes_step(params, lr, lora_c, opt_c, cl_mask, batch, sv, active)
+            return (lora_c, opt_c), loss
+
+        with jax.named_scope("client_train"):
+            (cl_lora, cl_opt), losses = jax.lax.scan(
+                step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
+            )
+        return cl_lora, cl_opt, losses
+
+    def train_lanes(params, lr, data, sample_valid, chosen, cl_lora, cl_opt, cl_mask,
+                    lane_client, batch_idx, step_valid):
+        # the cohort's rows, plus one scratch row per lane for a lane that
+        # trains no client; each step gathers the rows its lanes name (never
+        # one row twice), trains them, and scatters them back
+        k, n_lanes = chosen.shape[0], lane_client.shape[0]
+
+        def with_scratch(tree):
+            return jax.tree.map(
+                lambda x: jnp.concatenate([x, jnp.zeros((n_lanes,) + x.shape[1:], x.dtype)]),
+                tree,
+            )
+
+        rows = jnp.concatenate([chosen, jnp.zeros((n_lanes,), chosen.dtype)])
+        mask_c = with_scratch(cl_mask) if use_neuron_mask else None
+
+        def step(carry, xs):
+            lora_c, opt_c = carry
+            lc, bidx, active = xs  # (L,) each
+            pop = rows[lc]
+            batch = {kk: v[pop, bidx] for kk, v in data.items()}
+            sv = sample_valid[pop, bidx]
+            mk = _gather(mask_c, lc) if use_neuron_mask else None
+            loss, lo, op = lanes_step(
+                params, lr, _gather(lora_c, lc), _gather(opt_c, lc), mk, batch, sv, active
+            )
+            lora_c = _scatter(lora_c, lc, lo, unique=True)
+            return (lora_c, _scatter(opt_c, lc, op, unique=True)), loss
+
+        with jax.named_scope("client_train"):
+            (lora_c, opt_c), losses = jax.lax.scan(
+                step,
+                (with_scratch(cl_lora), with_scratch(cl_opt)),
+                (lane_client.T, batch_idx.T, step_valid.T),
+            )
+            cohort = lambda tree: jax.tree.map(lambda x: x[:k], tree)  # noqa: E731
+            return cohort(lora_c), cohort(opt_c), losses
+
+    def body(params, global_lora, stacked_lora, stacked_opt, neuron_mask, gal_mask,
+             data: Dict[str, Any], sample_valid, chosen, plan, weights, lr,
+             stacked_residual=None, comp_mask=None):
         # named scopes (gather, merge_in, client_train, fedavg, scatter)
         # label the program's ops in a profile; they change no number
         with jax.named_scope("gather"):
@@ -251,8 +333,8 @@ def _round_body(
             cl_opt = shard(_gather(stacked_opt, chosen))
             cl_mask = shard(_gather(neuron_mask, chosen)) if use_neuron_mask else None
             if hoist_client_data:
-                cl_data = shard({kk: v[chosen] for kk, v in data.items()})
-                cl_sv = shard(sample_valid[chosen])
+                data = shard({kk: v[chosen] for kk, v in data.items()})
+                sample_valid = shard(sample_valid[chosen])
 
         # line 15: overwrite the GAL part of each client's LoRA with the
         # global copy; gal_mask leaves broadcast over the client axis. The
@@ -263,41 +345,10 @@ def _round_body(
                 global_lora, cl_lora, gal_mask,
             )
 
-        client_step = make_client_step(loss_fn, opt_update)
-
-        def one_step(lo, op, mk, batch, sv, act):
-            return client_step(params, lo, op, mk, batch, sv, lr, act)
-
-        def step(carry, xs):
-            lora_c, opt_c = carry
-            bidx, active = xs  # (k,), (k,)
-            if hoist_client_data:
-                # per-client batch pick stays aligned on the k axis (no
-                # cross-device gather inside the scan)
-                batch = shard(
-                    {kk: jax.vmap(lambda d, j: d[j])(v, bidx) for kk, v in cl_data.items()}
-                )
-                sv = shard(jax.vmap(lambda d, j: d[j])(cl_sv, bidx))
-            else:
-                batch = {kk: v[chosen, bidx] for kk, v in data.items()}
-                sv = sample_valid[chosen, bidx]
-            # padded steps compute but do not commit: the optimizer's
-            # ``active`` predicate holds LoRA, moments, and Adam's step
-            # counter in the same pass (exactly like the loop engine)
-            if use_neuron_mask:
-                loss, lora_c, opt_c = jax.vmap(one_step)(
-                    lora_c, opt_c, cl_mask, batch, sv, active
-                )
-            else:
-                loss, lora_c, opt_c = jax.vmap(
-                    lambda lo, op, b, m, a: one_step(lo, op, None, b, m, a)
-                )(lora_c, opt_c, batch, sv, active)
-            return (lora_c, opt_c), loss
-
-        with jax.named_scope("client_train"):
-            (cl_lora, cl_opt), losses = jax.lax.scan(
-                step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
-            )
+        train = train_lanes if packed else train_cohort
+        cl_lora, cl_opt, losses = train(
+            params, lr, data, sample_valid, chosen, cl_lora, cl_opt, cl_mask, *plan
+        )
 
         if compress is None:
             # line 18: weighted FedAvg fused over the GAL part only; with the
@@ -351,6 +402,28 @@ def _round_body(
                 _scatter(stacked_residual, chosen, new_res) if ef else stacked_residual,
             )
 
+    if packed:
+
+        def packed_round_fn(params, global_lora, stacked_lora, stacked_opt, neuron_mask,
+                            gal_mask, data, sample_valid, chosen, lane_client, batch_idx,
+                            step_valid, weights, lr, stacked_residual=None, comp_mask=None):
+            return body(
+                params, global_lora, stacked_lora, stacked_opt, neuron_mask, gal_mask, data,
+                sample_valid, chosen, (lane_client, batch_idx, step_valid), weights, lr,
+                stacked_residual, comp_mask,
+            )
+
+        return packed_round_fn
+
+    def round_fn(params, global_lora, stacked_lora, stacked_opt, neuron_mask, gal_mask,
+                 data, sample_valid, chosen, batch_idx, step_valid, weights, lr,
+                 stacked_residual=None, comp_mask=None):
+        return body(
+            params, global_lora, stacked_lora, stacked_opt, neuron_mask, gal_mask, data,
+            sample_valid, chosen, (batch_idx, step_valid), weights, lr,
+            stacked_residual, comp_mask,
+        )
+
     return round_fn
 
 
@@ -388,6 +461,36 @@ def build_compressed_round_fn(
     return jax.jit(body, donate_argnums=(1, 2, 3, 13))
 
 
+def build_packed_round_fn(
+    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool, compress=None
+) -> Callable:
+    """The round program of :func:`build_round_fn` (or, with ``compress``,
+    :func:`build_compressed_round_fn`) training the cohort in ``L < k`` lanes.
+
+    ``round_fn(params, global_lora, stacked_lora, stacked_opt, neuron_mask,
+    gal_mask, data, sample_valid, chosen, lane_client, batch_idx, step_valid,
+    weights, lr[, stacked_residual, comp_mask]) -> (new_global_lora,
+    new_stacked_lora, new_stacked_opt, losses (S, L)[, new_residual])``, with
+    ``lane_client``/``batch_idx``/``step_valid`` (L, S) from
+    :func:`repro.core.curriculum.pack_lanes`. ``gather``, ``merge_in``,
+    ``fedavg`` and ``scatter`` run over the cohort as in the unpacked
+    program; the ``client_train`` scan carries the cohort's LoRA and
+    optimizer rows (plus L scratch rows) and at each step trains the L rows
+    the lanes name, with the same client step under ``vmap``. Every client
+    trains the same batches in the same order from the same merged start,
+    its Adam counter in its own row: only the schedule of lane-steps
+    changes, so a lane no longer pads a short client to the longest one.
+    Single device only: the sharded engine keeps one lane per client, which
+    its mesh shards.
+    """
+    body = _round_body(
+        loss_fn, opt_update, use_neuron_mask=use_neuron_mask, compress=compress,
+        packed=True,
+    )
+    donate = (1, 2, 3) if compress is None else (1, 2, 3, 14)
+    return jax.jit(body, donate_argnums=donate)
+
+
 def _cohort_round_body(
     loss_fn: Callable,
     opt_update: Callable,
@@ -405,7 +508,8 @@ def _cohort_round_body(
     aggregation, minus the gather/scatter bookends. Data arrives as the
     cohort's own ``(k, NB, B, ...)`` grid (``stack_cohort``), already
     bucketed so every round with the same (k, NB, S) shape reuses one
-    compiled program.
+    compiled program. It keeps one lane per client: lane packing
+    (:func:`build_packed_round_fn`) is the in-memory stack's alone.
     """
 
     def round_fn(
@@ -542,7 +646,9 @@ def build_sharded_round_fn(
     their leading client axis on the mesh's dp axes; params / global LoRA /
     the GAL mask / the step plan are replicated. Requires the stack's client
     count C and the padded cohort size k to be multiples of
-    ``launch.mesh.num_client_groups(mesh)`` (the runner pads both).
+    ``launch.mesh.num_client_groups(mesh)`` (the runner pads both). It keeps
+    one lane per client, sharded over the mesh: the runner packs lanes
+    (:func:`build_packed_round_fn`) on one device only.
     """
     client = client_sharding(mesh)
     repl = replicated_sharding(mesh)
@@ -625,7 +731,7 @@ def _client_train_body(
     The same ``make_client_step`` body as the vectorized round program, but
     scanned for a *single* client with no vmap barrier — the async engine
     dispatches one of these per completion event, so a fast client's program
-    never waits on a straggler's.
+    never waits on a straggler's (and has no lanes to pack).
     """
     client_step = make_client_step(loss_fn, opt_update)
 

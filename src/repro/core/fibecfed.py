@@ -504,6 +504,8 @@ class FibecFed:
         # sync engines record (chosen, client_steps) per round so benchmarks
         # can price the round barrier under a hetero.ScenarioPreset
         self.last_round_info: Optional[Dict[str, np.ndarray]] = None
+        # packed-round lane counts by scan length: _lane_count
+        self._lane_counts: Dict[int, int] = {}
 
     # ------------------------------------------------------------------
     # stacked client state (ownership lives on the store)
@@ -693,6 +695,45 @@ class FibecFed:
             ("round", loss_fn, self._opt_key, use_mask),
             lambda: eng.build_round_fn(loss_fn, opt_update, use_neuron_mask=use_mask),
         )
+
+    def _packed_round_fn(self):
+        """The single-device round program over a :func:`curr.pack_lanes`
+        plan (:func:`eng.build_packed_round_fn`), with or without
+        compression; :meth:`_round_fn` stays the unpacked program."""
+        loss_fn, opt_update = self.loss_fn, self.opt_update
+        use_mask = self._stacked_mask is not None
+        comp = self._compress_static()
+        ckey = None if comp is None else tuple(sorted(comp.items()))
+        return _memo(
+            ("round_packed", loss_fn, self._opt_key, use_mask, ckey),
+            lambda: eng.build_packed_round_fn(
+                loss_fn, opt_update, use_neuron_mask=use_mask, compress=comp
+            ),
+        )
+
+    def _lane_count(self, S: int) -> int:
+        """Lanes of a packed round at scan length ``S``
+        (:func:`curr.lane_count` over the whole population and schedule):
+        the same for every round and cohort, so one packed program per step
+        bucket."""
+        if S not in self._lane_counts:
+            k = min(self.fl.devices_per_round, len(self.clients))
+            self._lane_counts[S] = curr.lane_count(
+                self.schedule, [len(c.order) for c in self.clients], k, S,
+                self.fl.local_epochs,
+            )
+        return self._lane_counts[S]
+
+    def _lane_plan(self, batch_idx, step_valid):
+        """The cohort's :func:`curr.pack_lanes` plan at the bucket's lane
+        count, or ``None`` for the unpacked program: where the lane count is
+        no less than the cohort (balanced shards), or the cohort does not
+        fit."""
+        k, S = batch_idx.shape
+        L = self._lane_count(S)
+        if L >= k:
+            return None
+        return curr.pack_lanes([bi[sv > 0] for bi, sv in zip(batch_idx, step_valid)], S, L)
 
     def _cohort_round_fn(self, use_mask: bool):
         """Round program over a *materialized cohort* (out-of-core store):
@@ -1210,6 +1251,10 @@ class FibecFed:
             m.gauge("jit.round_fn_traces").set(
                 eng.trace_cache_size(self._round_fn())
             )
+            if self.mesh is None:
+                m.gauge("jit.packed_round_fn_traces").set(
+                    eng.trace_cache_size(self._packed_round_fn())
+                )
         return stats
 
     def _dispatch_round(self, t: int, lr: Optional[float] = None) -> Dict[str, float]:
@@ -1300,6 +1345,10 @@ class FibecFed:
             )
             w = np.asarray([self.clients[ci].n for ci in chosen], np.float64)
             w = (w / w.sum()).astype(np.float32)
+            # one device: pack the cohort's ragged step runs into fewer
+            # lanes where the population's skew allows (the sharded engine's
+            # lanes are the mesh's client axis)
+            lanes = self._lane_plan(batch_idx, step_valid) if self.mesh is None else None
 
             if self._cohort_pad > k:
                 # sharded engine: pad the cohort onto the stack's inert padding
@@ -1326,8 +1375,7 @@ class FibecFed:
                 self._stack_data,
                 self._sample_valid,
                 jnp.asarray(chosen, jnp.int32),
-                jnp.asarray(batch_idx),
-                jnp.asarray(step_valid),
+                *map(jnp.asarray, (batch_idx, step_valid) if lanes is None else lanes),
                 jnp.asarray(w),
                 jnp.float32(lr),
             )
@@ -1344,7 +1392,7 @@ class FibecFed:
                 )
                 args += (res_arg, cm_arg)
         with _round_phase(tel, "dispatch"):
-            round_fn = self._round_fn()
+            round_fn = self._round_fn() if lanes is None else self._packed_round_fn()
             if self.compression is None:
                 self.global_lora, self._stacked_lora, self._stacked_opt, losses = (
                     round_fn(*args)
@@ -1361,10 +1409,15 @@ class FibecFed:
                     self._stacked_residual = new_res
 
         with _round_phase(tel, "wait"):
-            losses = np.asarray(losses)  # (S, k)
+            losses = np.asarray(losses)  # (S, lanes)
         with _round_phase(tel, "account"):
-            valid = step_valid.T
+            valid = (step_valid if lanes is None else lanes[2]).T
             mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
+            # how often packing engages, and the lane-steps the program ran
+            runtime_metrics.counter(
+                "fl.rounds_unpacked" if lanes is None else "fl.rounds_packed"
+            ).inc()
+            runtime_metrics.histogram("fl.round_scanned_steps").observe(losses.size)
 
             self.last_round_info = {
                 "chosen": np.asarray(chosen[:k]),
