@@ -16,7 +16,6 @@ import numpy as np
 
 from bench.lib import compare
 from bench.lib import fl_reference
-from bench.lib import flops
 from bench.lib.fljob import FLJob, host
 
 SPANS = ("difficulty", "sensitivity", "fim_warmup")
@@ -46,8 +45,9 @@ class Job(FLJob):
         the gradient with respect to the embeddings of each client's easiest
         batch plus two forward passes of it without the head."""
         s, T, B = self.sizes, self.seq_len, self.traffic["batch_size"]
-        grad = flops.lora_train_flops(s, T, loss_positions=1)
-        probe = flops.input_grad_flops(s, T, loss_positions=1) + 2 * flops.forward_flops(s, T, 0)
+        ref = self.ref
+        grad = ref.lora_train_flops(s, T, loss_positions=1)
+        probe = ref.input_grad_flops(s, T, loss_positions=1) + 2 * ref.forward_flops(s, T, 0)
         E = self.traffic["fim_warmup_epochs"]
         total = 0.0
         for n, order in zip(self.shards, self.orders):

@@ -17,7 +17,6 @@ import numpy as np
 
 from bench.lib import compare
 from bench.lib import fl_reference
-from bench.lib import flops
 from bench.lib.fljob import FLJob, host
 
 
@@ -82,16 +81,15 @@ class Job(FLJob):
         chosen, steps = np.asarray(info["chosen"]), np.asarray(info["client_steps"])
         if not np.array_equal(steps, self.batches[chosen]):
             raise SystemExit("a client trained less than its whole shard")
-        S = int(st["padded_steps"])
         return {"loss": st["loss"], "samples": int(self.shards[chosen].sum()),
-                "real_steps": int(steps.sum()), "scanned_steps": len(chosen) * S}
+                "real_steps": int(steps.sum())}
 
     def end_to_end(self, window_s: float, steps: List[Dict[str, Any]]) -> Dict[str, float]:
         tokens = sum(s["samples"] for s in steps) * self.seq_len
         return {"train_tokens_per_s": tokens / window_s}
 
     def required_flops(self, steps: List[Dict[str, Any]]) -> float:
-        per_seq = flops.lora_train_flops(self.sizes, self.seq_len, loss_positions=1)
+        per_seq = self.ref.lora_train_flops(self.sizes, self.seq_len, loss_positions=1)
         return per_seq * sum(s["samples"] for s in steps)
 
     # -- the comparison with the reference --------------------------------------

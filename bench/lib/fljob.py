@@ -1,17 +1,20 @@
 """The federated job every cell drives: a ``FibecFed`` runner built through
-``make_runner`` over the benchmark's own weights and data."""
+``make_runner`` over the benchmark's own weights and data.
+
+The architecture comes from the configuration's reference module (``ref``):
+its ``sizes``, its ``make_weights`` and its operation counts. Nothing here
+knows a layout."""
 from __future__ import annotations
 
 import dataclasses
+import typing
 from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import flops
 from bench.lib import traffic as gen
-from bench.lib import weights as wts
+from bench.lib.weights import check_layout
 
 # published config.json keys -> the program's ModelConfig fields
 HF_TO_MODEL = {
@@ -31,7 +34,20 @@ def model_config(config: Dict[str, Any]):
 
     kw = {dst: config[src] for src, dst in HF_TO_MODEL.items()}
     kw.update(config["run_as"])
-    return ModelConfig(name=config["name"], **kw)
+    return _from_dict(ModelConfig, dict(kw, name=config["name"]))
+
+
+def _from_dict(cls, kw: Dict[str, Any]):
+    """``cls(**kw)``, where a dict under a field whose type is a dataclass (or
+    an ``Optional`` of one) becomes that dataclass, by the same rule. A key
+    that is no field raises ``TypeError``."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in kw.items():
+        hint = hints.get(k)
+        sub = next((t for t in (hint, *typing.get_args(hint)) if dataclasses.is_dataclass(t)), None)
+        out[k] = _from_dict(sub, v) if sub is not None and isinstance(v, dict) else v
+    return cls(**out)
 
 
 def host(tree):
@@ -55,17 +71,16 @@ class FLJob:
         self.config, self.traffic, self.seed, self.ref = config, traffic, seed, reference
         self.chips = chips
         self.cfg = model_config(config)
-        self.sizes = flops.sizes(config)
+        self.sizes = reference.sizes(config)
         self.seq_len = traffic["seq_len"]
         self.shards = gen.shard_sizes(traffic)
         self.batches = np.asarray([gen.batches_of(n, traffic["batch_size"]) for n in self.shards])
 
         model = build_model(self.cfg)
         key = jax.random.PRNGKey(0)
-        params, lora = wts.make_weights(seed, self.sizes, qkv_bias=self.cfg.qkv_bias,
-                                        qk_norm=self.cfg.qk_norm, dtype=self.cfg.dtype)
-        wts.check_layout(params, jax.eval_shape(model.init_params, key), "base weight")
-        wts.check_layout(lora, jax.eval_shape(model.init_lora, key), "LoRA")
+        params, lora = reference.make_weights(seed, self.sizes, config["run_as"])
+        check_layout(params, jax.eval_shape(model.init_params, key), "base weight")
+        check_layout(lora, jax.eval_shape(model.init_lora, key), "LoRA")
         self.params = params
         self.lora0 = host(lora)  # the reference's copy: the program donates its own
         # handed over once: the program keeps its models (and so these

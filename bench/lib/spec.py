@@ -11,7 +11,9 @@ Everything a cell needs sits in files of its own, found through the names in
 - each per-layer metric's reader: ``bench/metrics/<metric>.py``, a module
   with ``read(ctx) -> float | None``;
 - the plain reference of a configuration: ``bench/reference/<name>.py``,
-  named by the configuration's ``reference`` key.
+  named by the configuration's ``reference`` key. It is also where the
+  harness learns the architecture: the module gives the hooks in
+  ``ARCH_HOOKS`` (``bench/README.md`` states their contract).
 
 A later change adds a cell, a configuration or a metric by adding such files
 and ``BENCHMARK.json`` entries; nothing here names one.
@@ -24,6 +26,9 @@ from pathlib import Path
 from typing import Any, Callable, Dict, List
 
 BENCH_DIR = "bench"
+# what a reference module gives the harness: the shapes, the weights, and the
+# operations the algorithm requires
+ARCH_HOOKS = ("sizes", "make_weights", "lora_train_flops", "input_grad_flops", "forward_flops")
 
 
 class SpecError(Exception):
@@ -97,8 +102,14 @@ def load_reader(root: Path, metric: str) -> Callable[[Dict[str, Any]], Any]:
 
 
 def load_reference(root: Path, name: str):
-    return _load_module(Path(root) / BENCH_DIR / "reference" / f"{name}.py",
-                        "bench_reference_" + name.replace(".", "_").replace("-", "_"))
+    """The reference module ``name``; refuses one that lacks an architecture
+    hook, before anything is built from it."""
+    path = Path(root) / BENCH_DIR / "reference" / f"{name}.py"
+    mod = _load_module(path, "bench_reference_" + name.replace(".", "_").replace("-", "_"))
+    missing = [h for h in ARCH_HOOKS if not callable(getattr(mod, h, None))]
+    if missing:
+        raise SpecError(f"{path} lacks the architecture hook(s) {', '.join(missing)}")
+    return mod
 
 
 def load_job(root: Path, kind: str):

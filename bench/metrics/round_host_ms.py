@@ -13,7 +13,7 @@ PHASES = ("plan", "put", "dispatch", "account")
 def read(ctx):
     from repro.obs import runtime_metrics
 
-    n = sum(1 for s in ctx["steps"] if "scanned_steps" in s)
+    n = sum(1 for s in ctx["steps"] if "real_steps" in s)
     recent = [getattr(runtime_metrics.histogram(f"fl.round_{p}_s"), "recent", None) for p in PHASES]
     if not n or any(r is None or len(r) < n for r in recent):
         return None

@@ -4,7 +4,7 @@ the window's rounds."""
 
 
 def read(ctx):
-    trace, rounds = ctx.get("trace"), [s for s in ctx["steps"] if "scanned_steps" in s]
+    trace, rounds = ctx.get("trace"), [s for s in ctx["steps"] if "real_steps" in s]
     if not trace or not rounds:
         return None
     t = sum(v for k, v in trace.get("modules_s", {}).items() if "round" in k)

@@ -11,7 +11,7 @@ window's end and the readers); real steps from the window's own counts.
 def read(ctx):
     from repro.obs import runtime_metrics
 
-    steps = [s for s in ctx["steps"] if "scanned_steps" in s]
+    steps = [s for s in ctx["steps"] if "real_steps" in s]
     recent = getattr(runtime_metrics.histogram("fl.round_scanned_steps"), "recent", None)
     if not steps or recent is None or len(recent) < len(steps):
         return None

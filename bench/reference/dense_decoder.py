@@ -16,14 +16,21 @@ float32 either way.
 The loss is the class-label objective the job trains: cross entropy of the
 label token against the logits after the last position, averaged over the
 valid samples of a batch. The head is applied at that position alone.
+
+The module also gives the harness the architecture's hooks (see
+``bench/README.md``): ``sizes``, ``make_weights`` and the operation counts
+``lora_train_flops``, ``input_grad_flops`` and ``forward_flops``.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from bench.lib.weights import jax_seed
 
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
@@ -249,3 +256,132 @@ def make_sensitivity(model: Model, prec: Precision, gamma: float):
         return jnp.mean(jnp.abs(pert - clean) / jnp.maximum(clean, 1e-12), axis=-1)
 
     return jax.jit(fn)
+
+
+# -- architecture hooks: sizes, weights, required operations ---------------------
+
+
+def sizes(config: Dict[str, Any]) -> Dict[str, int]:
+    """The shapes of a dense decoder from a configuration file's keys."""
+    run = config.get("run_as", {})
+    d = config["hidden_size"]
+    h = config["num_attention_heads"]
+    hd = run.get("head_dim") or config.get("head_dim") or d // h
+    return {
+        "layers": config["num_hidden_layers"],
+        "d_model": d,
+        "heads": h,
+        "kv_heads": config["num_key_value_heads"],
+        "head_dim": hd,
+        "d_ff": config["intermediate_size"],
+        "vocab": config["vocab_size"],
+        "rank": run.get("lora_rank", 8),
+    }
+
+
+def _dims(s: Dict[str, Any]) -> Dict[str, tuple]:
+    d, q, kv = s["d_model"], s["heads"] * s["head_dim"], s["kv_heads"] * s["head_dim"]
+    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
+
+
+def _make(key, s: Dict[str, Any], qkv_bias: bool, qk_norm: bool, dtype):
+    L, d, f, V, hd, r = s["layers"], s["d_model"], s["d_ff"], s["vocab"], s["head_dim"], s["rank"]
+    keys = iter(jax.random.split(key, 32))
+
+    def normal(shape, scale):
+        return jax.random.normal(next(keys), shape, jnp.float32) * scale
+
+    def dense(d_in, d_out):
+        return normal((L, d_in, d_out), 1.0 / math.sqrt(d_in))
+
+    def norm_w(shape):
+        return 1.0 + normal(shape, 0.1)
+
+    layers = {t: dense(*io) for t, io in _dims(s).items()}
+    if qkv_bias:
+        for t, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")):
+            layers[b] = normal((L, _dims(s)[t][1]), 0.1)
+    if qk_norm:
+        layers["q_norm_w"] = norm_w((L, hd))
+        layers["k_norm_w"] = norm_w((L, hd))
+    layers["attn_norm_w"] = norm_w((L, d))
+    layers["mlp_norm_w"] = norm_w((L, d))
+    layers["w_gate"] = dense(d, f)
+    layers["w_up"] = dense(d, f)
+    layers["w_down"] = dense(f, d)
+    params = {"embed": normal((V, d), 0.02), "layers": layers, "final_norm_w": norm_w((d,))}
+    params = jax.tree.map(lambda x: x.astype(dtype), params)
+    # LoRA: a ~ N(0, 1/r^2), b = 0, float32 (the standard init)
+    lora = {"layers": {
+        t: {"a": normal((L, io[0], r), 1.0 / r), "b": jnp.zeros((L, r, io[1]), jnp.float32)}
+        for t, io in sorted(_dims(s).items())
+    }}
+    return params, lora
+
+
+def make_weights(seed: int, s: Dict[str, Any], run_as: Dict[str, Any]):
+    """``(params, lora)`` on the default device, from ``seed``, in one jitted
+    call: the base in ``run_as["dtype"]``, LoRA in float32, laid out as the
+    program's ``init_decoder`` and ``init_lora`` lay them out."""
+    qkv_bias, qk_norm = bool(run_as["qkv_bias"]), bool(run_as["qk_norm"])
+    dtype = jnp.dtype(run_as["dtype"])
+    fn = jax.jit(lambda k: _make(k, s, qkv_bias, qk_norm, dtype))
+    return fn(jax.random.PRNGKey(jax_seed(seed)))
+
+
+# A multiply-add is two operations. Only what the result needs is counted:
+# the forward pass through the frozen base and the LoRA factors; the backward
+# pass that carries the gradient back to every layer's inputs (the frozen base
+# gets no weight gradient); the LoRA factors' input and weight gradients;
+# causal attention (scores and weighted values over the positions each query
+# sees); the LM head at only the positions the loss reads. Work the program
+# does beyond this (logits no loss reads, padded steps, recomputation) lowers
+# a utilization built on these counts.
+
+
+def _layer_weights(s: Dict[str, int]) -> int:
+    d, q, kv = s["d_model"], s["heads"] * s["head_dim"], s["kv_heads"] * s["head_dim"]
+    attn = d * q + 2 * d * kv + q * d
+    mlp = 3 * d * s["d_ff"]  # gate, up, down
+    return attn + mlp
+
+
+def _layer_lora(s: Dict[str, int]) -> int:
+    d, q, kv, r = s["d_model"], s["heads"] * s["head_dim"], s["kv_heads"] * s["head_dim"], s["rank"]
+    return sum(r * (i + o) for i, o in ((d, q), (d, kv), (d, kv), (q, d)))
+
+
+def _attention_fwd(s: Dict[str, int], seq_len: int) -> float:
+    """Scores and weighted values of causal attention for one sequence: query
+    ``i`` sees ``i + 1`` positions."""
+    visible = seq_len * (seq_len + 1) / 2
+    return 2 * 2 * s["heads"] * s["head_dim"] * visible
+
+
+def forward_flops(s: Dict[str, int], seq_len: int, head_positions: int) -> float:
+    """One sequence's forward pass, with the head at ``head_positions``."""
+    layer = 2 * seq_len * (_layer_weights(s) + _layer_lora(s)) + _attention_fwd(s, seq_len)
+    return s["layers"] * layer + 2 * head_positions * s["d_model"] * s["vocab"]
+
+
+def lora_train_flops(s: Dict[str, int], seq_len: int, loss_positions: int = 1) -> float:
+    """One sequence through forward and backward of LoRA training on a frozen
+    base, the loss read at ``loss_positions`` positions."""
+    base = 2 * seq_len * _layer_weights(s)
+    lora = 2 * seq_len * _layer_lora(s)
+    attn = _attention_fwd(s, seq_len)
+    # base: forward + input gradient; LoRA: forward + input + weight
+    # gradients; attention: forward + the gradients of both of its products
+    layer = 2 * base + 3 * lora + 3 * attn
+    head = 2 * loss_positions * s["d_model"] * s["vocab"]
+    return s["layers"] * layer + 2 * head  # head: forward + input gradient
+
+
+def input_grad_flops(s: Dict[str, int], seq_len: int, loss_positions: int = 1) -> float:
+    """One sequence's forward pass and the gradient with respect to its
+    inputs alone (no weight gradients), the loss at ``loss_positions``."""
+    base = 2 * seq_len * _layer_weights(s)
+    lora = 2 * seq_len * _layer_lora(s)
+    attn = _attention_fwd(s, seq_len)
+    head = 2 * loss_positions * s["d_model"] * s["vocab"]
+    return s["layers"] * (2 * base + 2 * lora + 3 * attn) + 2 * head

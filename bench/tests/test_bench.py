@@ -16,13 +16,17 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from bench.lib import flops, peaks, spec, xplane  # noqa: E402
+from bench.lib import peaks, spec, xplane  # noqa: E402
 
 TESTDATA = REPO / "bench" / "testdata"
 
 
 def _config(name):
     return json.loads((REPO / "bench" / "configs" / f"{name}.json").read_text())
+
+
+def _dense():
+    return spec.load_reference(REPO, "dense_decoder")
 
 
 # -- required operations ----------------------------------------------------------
@@ -39,17 +43,19 @@ def test_required_flops_qwen2_by_hand():
     head = 2 * (2 * 896 * 151936)  # one loss position, forward + input gradient
     want = 24 * layer + head
     assert want == 186_712_653_824
-    got = flops.lora_train_flops(flops.sizes(_config("qwen2-0.5b")), 128, loss_positions=1)
+    dense = _dense()
+    got = dense.lora_train_flops(dense.sizes(_config("qwen2-0.5b")), 128, loss_positions=1)
     assert got == pytest.approx(want, rel=1e-12)
     # per token, about 1.46 GFLOP
     assert got / 128 == pytest.approx(1.4587e9, rel=1e-4)
 
 
 def test_forward_flops_counts_the_head_where_asked():
-    s = flops.sizes(_config("qwen3-0.6b"))
+    dense = _dense()
+    s = dense.sizes(_config("qwen3-0.6b"))
     assert s["head_dim"] == 128 and s["kv_heads"] == 8
-    all_pos = flops.forward_flops(s, 128, head_positions=128)
-    last = flops.forward_flops(s, 128, head_positions=1)
+    all_pos = dense.forward_flops(s, 128, head_positions=128)
+    last = dense.forward_flops(s, 128, head_positions=1)
     assert all_pos - last == pytest.approx(2 * 127 * 1024 * 151936)
 
 

@@ -60,7 +60,7 @@ def test_round_host_ms_reads_nothing_without_the_window_rounds(monkeypatch):
     import repro.obs
 
     read = spec.load_reader(REPO, "round_host_ms")
-    ctx = {"steps": [{"scanned_steps": 4}] * 3}
+    ctx = {"steps": [{"real_steps": 4}] * 3}
     for n in (None, 2):
         monkeypatch.setattr(repro.obs, "runtime_metrics", _Registry(n))
         assert read(ctx) is None

@@ -40,10 +40,10 @@ def test_scanned_padding_share_reads_the_window_rounds(monkeypatch):
 
     read = spec.load_reader(REPO, "scanned_padding_share")
     # three window rounds of 7 lanes x 16 steps, after a set-up round of 160
-    ctx = {"steps": [{"scanned_steps": 160, "real_steps": r} for r in (84, 80, 88)]}
+    ctx = {"steps": [{"real_steps": r} for r in (84, 80, 88)]}
     monkeypatch.setattr(repro.obs, "runtime_metrics", _Registry([160, 112, 112, 112]))
     assert abs(read(ctx) - 100.0 * (336 - 252) / 336) < 1e-9
-    # unpacked rounds scan the cohort grid: the share is padded_step_share's
+    # an unpacked round scans the whole cohort grid, 10 clients x 16 steps
     monkeypatch.setattr(repro.obs, "runtime_metrics", _Registry([160, 160, 160]))
     assert abs(read(ctx) - 100.0 * (480 - 252) / 480) < 1e-9
 
@@ -52,7 +52,7 @@ def test_scanned_padding_share_reads_nothing_without_the_histogram(monkeypatch):
     import repro.obs
 
     read = spec.load_reader(REPO, "scanned_padding_share")
-    ctx = {"steps": [{"scanned_steps": 160, "real_steps": 84}] * 3}
+    ctx = {"steps": [{"real_steps": 84}] * 3}
     for values in (None, [], [112, 112]):
         monkeypatch.setattr(repro.obs, "runtime_metrics", _Registry(values))
         assert read(ctx) is None

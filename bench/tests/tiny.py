@@ -3,12 +3,15 @@
 ``make_root(dir)`` writes ``BENCHMARK.json`` and a tiny configuration,
 traffic mix and limits into ``dir`` and links the benchmark's own code and
 the program into it, so that ``run.main(root=dir, require_tpu=False)``
-drives a whole run at a size a test can hold.
+drives a whole run at a size a test can hold. ``reference=<module file>``
+links that file's directory in place of the benchmark's reference modules
+and names the module in the tiny configuration's ``reference``.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
+from typing import Optional
 
 REPO = Path(__file__).resolve().parents[2]
 CELL = "tiny.rounds"
@@ -29,14 +32,19 @@ TINY_INIT_LIMITS = {"difficulty_gap": 0.06, "fim_gap": 0.1, "sensitivity_gap": 0
                     "order_mismatch": 0.0, "mask_mismatch": 1e-3, "gal_mismatch": 0.0}
 
 
-def make_root(root: Path, *, kind: str = "rounds", base: str = "qwen2-0.5b", limits=None) -> Path:
+def make_root(root: Path, *, kind: str = "rounds", base: str = "qwen2-0.5b", limits=None,
+              reference: Optional[Path] = None) -> Path:
     root = Path(root)
     for d in ("configs", "traffic", "limits"):
         (root / "bench" / d).mkdir(parents=True, exist_ok=True)
-    for d in ("jobs", "lib", "metrics", "reference"):
+    for d in ("jobs", "lib", "metrics"):
         (root / "bench" / d).symlink_to(REPO / "bench" / d)
+    refs = REPO / "bench" / "reference" if reference is None else Path(reference).parent
+    (root / "bench" / "reference").symlink_to(refs)
     (root / "src").symlink_to(REPO / "src")
     cfg = json.loads((REPO / "bench" / "configs" / f"{base}.json").read_text())
+    if reference is not None:
+        cfg["reference"] = Path(reference).stem
     cfg.update(name="tiny", hidden_size=64, intermediate_size=128, num_attention_heads=4,
                num_key_value_heads=2, num_hidden_layers=2, vocab_size=512)
     cfg["run_as"]["head_dim"] = 16
