@@ -21,17 +21,52 @@ FAMILIES = ("dense", "moe", "ssm", "hybrid", "encdec", "vlm", "audio", "encoder"
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 0
+    num_experts: int = 0  # the router's width: every expert a token may pick
     top_k: int = 1
     d_ff_expert: int = 0
-    # Shared (always-on) expert, as in Llama-4.
+    # Shared (always-on) expert, as in Llama-4; DeepSeek's n_shared_experts
+    # run as one SwiGLU of their summed width.
     shared_expert: bool = False
     d_ff_shared: int = 0
     capacity_factor: float = 1.25
-    # Tokens are routed within groups of this size (keeps the dispatch one-hot
-    # tensor small; see DESIGN.md §3 MoE).
+    # Tokens are routed within groups of this size, so the dispatch one-hot
+    # tensor of the softmax router stays (groups, G, E, capacity).
     router_group_size: int = 512
     aux_loss_weight: float = 0.01
+    # "softmax": top-k of a softmax, capacity-dropping dispatch, aux loss.
+    # "sigmoid_bias" (DeepSeek-V3's noaux_tc): top-k of sigmoid scores plus a
+    # frozen correction bias, weighted by the unbiased scores normalised over
+    # the k, times ``routed_scale``; dropless, no aux loss.
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # sigmoid_bias only: the experts this chip holds, 0..held_experts-1 of
+    # num_experts (the chip's share of an expert-parallel split); None holds
+    # them all. The absent experts' part of the result is left out.
+    held_experts: Optional[int] = None
+    # leading layers with a dense SwiGLU of width ModelConfig.d_ff in place
+    # of the experts (DeepSeek's first_k_dense_replace)
+    first_dense_layers: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.held_experts is None else self.held_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): q from the hidden state
+    (no q compression), keys and values expanded from a normed latent of
+    ``kv_lora_rank``; each head's key is ``qk_nope_head_dim`` dims without
+    RoPE plus ``qk_rope_head_dim`` rotary dims shared by all heads."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -73,8 +108,11 @@ class ModelConfig:
     logit_soft_cap: Optional[float] = None
     tie_embeddings: bool = False
 
+    norm_eps: float = 1e-6  # every RMSNorm's epsilon
+
     # family-specific
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): one shared attention+mlp block applied every
     # `hybrid_period` SSM layers.
@@ -111,6 +149,12 @@ class ModelConfig:
             assert self.moe is not None and self.moe.num_experts > 0
         if self.family in ("ssm", "hybrid"):
             assert self.ssm is not None
+        if self.moe is not None and self.moe.router == "sigmoid_bias":
+            assert 0 < self.moe.held <= self.moe.num_experts, self.moe
+        if self.moe is not None:
+            # the held-expert layer runs in the MLA decoder, the softmax
+            # capacity layer in the GQA one
+            assert (self.moe.router == "sigmoid_bias") == (self.mla is not None), self.moe.router
 
     @property
     def resolved_head_dim(self) -> int:
@@ -143,7 +187,22 @@ class ModelConfig:
             lora_rank=4,
             dtype="float32",
         )
-        if self.moe is not None:
+        if self.moe is not None and self.moe.router == "sigmoid_bias":
+            # 16 experts, the published share held (8 of 64 -> 2 of 16)
+            E = min(self.moe.num_experts, 16)
+            small["moe"] = dataclasses.replace(
+                self.moe,
+                num_experts=E,
+                top_k=min(self.moe.top_k, 4),
+                d_ff_expert=min(self.moe.d_ff_expert, 64),
+                d_ff_shared=min(self.moe.d_ff_shared, 128) if self.moe.shared_expert else 0,
+                held_experts=(
+                    None if self.moe.held_experts is None
+                    else max(1, self.moe.held_experts * E // self.moe.num_experts)
+                ),
+                first_dense_layers=min(self.moe.first_dense_layers, 1),
+            )
+        elif self.moe is not None:
             small["moe"] = dataclasses.replace(
                 self.moe,
                 num_experts=min(self.moe.num_experts, 4),
@@ -151,6 +210,13 @@ class ModelConfig:
                 d_ff_expert=min(self.moe.d_ff_expert, 128),
                 d_ff_shared=min(self.moe.d_ff_shared, 128) if self.moe.shared_expert else 0,
                 router_group_size=64,
+            )
+        if self.mla is not None:
+            small["mla"] = MLAConfig(
+                kv_lora_rank=min(self.mla.kv_lora_rank, 32),
+                qk_nope_head_dim=min(self.mla.qk_nope_head_dim, 16),
+                qk_rope_head_dim=min(self.mla.qk_rope_head_dim, 8),
+                v_head_dim=min(self.mla.v_head_dim, 16),
             )
         if self.ssm is not None:
             small["ssm"] = dataclasses.replace(

@@ -16,6 +16,7 @@ from repro.configs.paligemma_3b import CONFIG as paligemma_3b
 from repro.configs.mamba2_1_3b import CONFIG as mamba2_1_3b
 from repro.configs.zamba2_7b import CONFIG as zamba2_7b
 from repro.configs.roberta_large import CONFIG as roberta_large
+from repro.configs.moonlight_16b_a3b import CONFIG as moonlight_16b_a3b
 
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c
@@ -31,6 +32,7 @@ ARCHS: Dict[str, ModelConfig] = {
         mamba2_1_3b,
         zamba2_7b,
         roberta_large,  # the paper's own model (extra, not in the assigned 10)
+        moonlight_16b_a3b,  # latent attention + sigmoid-routed held experts (extra)
     ]
 }
 

@@ -21,7 +21,9 @@ stacked buffers, so steady-state rounds allocate nothing persistent.
 
 The initialization phase gets the same treatment: difficulty scoring runs as
 one vmapped program over every (client, batch) cell, and the momentum-FIM
-warmup is a scan over warmup epochs of a vmap over clients.
+warmup is a scan over warmup epochs of a vmap over clients. Where those
+programs would not fit the device, the runner builds them over chunks of
+clients (``chunk=``, a ``lax.map`` of the vmap) with the same results.
 
 Mesh sharding (``engine="sharded"``): the leading client axis is the data-
 parallel axis of a device mesh. ``build_sharded_round_fn`` jits the *same*
@@ -109,7 +111,7 @@ def _masked_loss(loss_fn: Callable) -> Callable:
     )
 
 
-def make_client_step(loss_fn: Callable, opt_update: Callable) -> Callable:
+def make_client_step(loss_fn: Callable, opt_update: Callable, *, stats: bool = False) -> Callable:
     """One client's masked local SGD/AdamW step (Alg. 1 lines 16-17).
 
     ``step(params, lora, opt, mask, batch, sample_valid, lr, active=None) ->
@@ -122,7 +124,28 @@ def make_client_step(loss_fn: Callable, opt_update: Callable) -> Callable:
     counter — without the separate ``tree_where`` pass the engines used to
     run over every leaf (and the fused kernels fold the predicate into their
     single read/write pass).
+
+    With ``stats`` and a loss that keeps counts (``loss_fn.masked_stats``:
+    the held-expert MoE), the step's loss comes back as ``(loss, counts)``:
+    the model's counts, ``held_assignments`` zero on an inactive step (whose
+    ``expert_rows`` were computed all the same); they carry no gradient.
+    Otherwise ``stats`` changes nothing.
     """
+    masked_stats = getattr(loss_fn, "masked_stats", None) if stats else None
+    if masked_stats is not None:
+
+        def stats_step(params, lora, opt, mask, batch, sample_valid, lr, active=None):
+            (loss, st), grads = jax.value_and_grad(
+                lambda x: masked_stats(params, x, batch, sample_valid), has_aux=True
+            )(lora)
+            with jax.named_scope("adamw"):
+                new_lora, new_opt = opt_update(grads, opt, lora, lr, mask, active)
+            if active is not None:
+                held = st["held_assignments"]
+                st = dict(st, held_assignments=held * active.astype(held.dtype))
+            return (loss, st), new_lora, new_opt
+
+        return stats_step
     masked = _masked_loss(loss_fn)
 
     def one_step(params, lora, opt, mask, batch, sample_valid, lr, active=None):
@@ -241,8 +264,11 @@ def _round_body(
     :func:`repro.core.curriculum.pack_lanes` plan: the program takes
     ``lane_client`` (L, S) after ``chosen``, ``batch_idx``/``step_valid`` are
     (L, S), and the losses come back (S, L). See :func:`build_packed_round_fn`.
+
+    With a loss that keeps counts (``loss_fn.masked_stats``), ``losses`` is
+    the pair ``(losses, counts)``, each count shaped as the losses.
     """
-    client_step = make_client_step(loss_fn, opt_update)
+    client_step = make_client_step(loss_fn, opt_update, stats=True)
 
     def lanes_step(params, lr, lora_c, opt_c, mask_c, batch, sv, active):
         # one curriculum step of every lane: the client step vmapped over
@@ -791,7 +817,16 @@ def build_client_train_fn(
     return jax.jit(body, donate_argnums=(2, 3))
 
 
-def _difficulty_body(loss_fn: Callable, metric: str) -> Callable:
+def _over_clients(one: Callable, xs, chunk):
+    """``one`` over the leading client axis of ``xs``: all clients at once
+    (``vmap``), or ``chunk`` clients at a time (``lax.map``), so that an init
+    program's temporaries hold a chunk's sequences, not every client's."""
+    if chunk is None:
+        return jax.vmap(one)(*xs)
+    return jax.lax.map(lambda x: one(*x), xs, batch_size=chunk)
+
+
+def _difficulty_body(loss_fn: Callable, metric: str, chunk=None) -> Callable:
     if metric == "fisher":
 
         def per_client(params, lora, cdata, csv):
@@ -810,39 +845,41 @@ def _difficulty_body(loss_fn: Callable, metric: str) -> Callable:
 
     @jax.named_scope("difficulty_grads")
     def diff(params, stacked_lora, data, sample_valid):
-        # lora is vmapped alongside the data: clients start from identical
+        # lora is mapped alongside the data: clients start from identical
         # copies, but a re-init after training must score each client's own
         # (trained, merged) LoRA exactly like the loop engine does
-        return jax.vmap(lambda lo, cd, cv: per_client(params, lo, cd, cv))(
-            stacked_lora, data, sample_valid
+        return _over_clients(
+            lambda lo, cd, cv: per_client(params, lo, cd, cv),
+            (stacked_lora, data, sample_valid), chunk,
         )
 
     return diff
 
 
-def build_difficulty_fn(loss_fn: Callable, metric: str) -> Callable:
+def build_difficulty_fn(loss_fn: Callable, metric: str, chunk=None) -> Callable:
     """Jitted (C, NB) difficulty scorer over the padded client stack.
 
     ``metric`` is "fisher" (Formula 17, via :func:`fisher.batch_fisher_scores`)
     or "loss" (masked mean inference loss). Host-side metrics (length, random)
-    never hit the device and stay in the orchestrator.
+    never hit the device and stay in the orchestrator. ``chunk`` scores that
+    many clients at a time (same scores, fewer sequences in flight).
     """
-    return jax.jit(_difficulty_body(loss_fn, metric))
+    return jax.jit(_difficulty_body(loss_fn, metric, chunk))
 
 
-def build_sharded_difficulty_fn(loss_fn: Callable, metric: str, mesh) -> Callable:
+def build_sharded_difficulty_fn(loss_fn: Callable, metric: str, mesh, chunk=None) -> Callable:
     """Difficulty scorer with each device scoring its shard of clients; the
     (C, NB) score grid is replicated on return (the host sorts it anyway)."""
     client = client_sharding(mesh)
     repl = replicated_sharding(mesh)
     return jax.jit(
-        _difficulty_body(loss_fn, metric),
+        _difficulty_body(loss_fn, metric, chunk),
         in_shardings=(repl, client, client, client),
         out_shardings=repl,
     )
 
 
-def _fim_warmup_body(loss_fn: Callable, momentum: float) -> Callable:
+def _fim_warmup_body(loss_fn: Callable, momentum: float, chunk=None) -> Callable:
     def per_client(params, lora, cdata, csv):
         zero = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), lora)
 
@@ -862,32 +899,32 @@ def _fim_warmup_body(loss_fn: Callable, momentum: float) -> Callable:
 
     @jax.named_scope("fim_warmup_program")
     def warm(params, stacked_lora, wdata, wsv):
-        return jax.vmap(lambda lo, cd, cv: per_client(params, lo, cd, cv))(
-            stacked_lora, wdata, wsv
+        return _over_clients(
+            lambda lo, cd, cv: per_client(params, lo, cd, cv), (stacked_lora, wdata, wsv), chunk
         )
 
     return warm
 
 
-def build_fim_warmup_fn(loss_fn: Callable, momentum: float) -> Callable:
+def build_fim_warmup_fn(loss_fn: Callable, momentum: float, chunk=None) -> Callable:
     """Jitted momentum-FIM warmup over all clients at once.
 
     ``warm(params, stacked_lora, wdata, wsv)`` with warmup batches stacked to
     ``(C, E, B, ...)`` returns the per-client momentum diag-FIM trees stacked
     to ``(C, ...)`` — a scan over the E warmup epochs of a vmap over clients,
     replaying ``fim_momentum_update`` (first epoch initializes, later epochs
-    blend with momentum).
+    blend with momentum). ``chunk`` warms that many clients at a time.
     """
-    return jax.jit(_fim_warmup_body(loss_fn, momentum))
+    return jax.jit(_fim_warmup_body(loss_fn, momentum, chunk))
 
 
-def build_sharded_fim_warmup_fn(loss_fn: Callable, momentum: float, mesh) -> Callable:
+def build_sharded_fim_warmup_fn(loss_fn: Callable, momentum: float, mesh, chunk=None) -> Callable:
     """FIM warmup with clients sharded over the mesh; the stacked FIM trees
     stay client-sharded (they feed the client-sharded neuron masks)."""
     client = client_sharding(mesh)
     repl = replicated_sharding(mesh)
     return jax.jit(
-        _fim_warmup_body(loss_fn, momentum),
+        _fim_warmup_body(loss_fn, momentum, chunk),
         in_shardings=(repl, client, client, client),
         out_shardings=client,
     )

@@ -109,6 +109,29 @@ def _memo(key, build):
     return _PROGRAM_MEMO[key]
 
 
+def _compile_within(fn, args):
+    """``fn`` compiled for ``args``, or None where the compiler finds that
+    it does not fit the device's memory (it refuses such a program)."""
+    try:
+        return fn.lower(*args).compile()
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" not in str(e):
+            raise
+        return None
+
+
+def _program_bytes(compiled, in_use: int = 0) -> int:
+    """Device bytes a compiled program needs: its arguments, outputs and
+    temporaries (aliased outputs once), plus ``in_use`` bytes the device
+    holds besides the arguments. 0 where the backend gives no analysis."""
+    m = compiled.memory_analysis()
+    if m is None:
+        return 0
+    args = m.argument_size_in_bytes
+    own = args + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    return own + max(0, in_use - args)
+
+
 @contextmanager
 def _round_phase(tel, name: str) -> Iterator[None]:
     """A host phase of a stacked-engine round: the span ``round_<name>`` on
@@ -506,6 +529,10 @@ class FibecFed:
         self.last_round_info: Optional[Dict[str, np.ndarray]] = None
         # packed-round lane counts by scan length: _lane_count
         self._lane_counts: Dict[int, int] = {}
+        # the init programs' memory budget (None: the device's) and the
+        # chunk of clients each ran with (_run_init_program)
+        self._init_bytes_limit: Optional[int] = None
+        self.init_chunks: Dict[str, Optional[int]] = {}
 
     # ------------------------------------------------------------------
     # stacked client state (ownership lives on the store)
@@ -624,29 +651,74 @@ class FibecFed:
 
     # vectorized-engine programs -----------------------------------------
 
-    def _difficulty_fn(self):
+    def _difficulty_fn(self, chunk: Optional[int] = None):
         loss_fn, metric, mesh = self.loss_fn, self.difficulty_metric, self.mesh
+        tail = () if chunk is None else (chunk,)
         if mesh is not None:
             return _memo(
-                ("difficulty", loss_fn, metric, mesh),
-                lambda: eng.build_sharded_difficulty_fn(loss_fn, metric, mesh),
+                ("difficulty", loss_fn, metric, mesh) + tail,
+                lambda: eng.build_sharded_difficulty_fn(loss_fn, metric, mesh, chunk),
             )
         return _memo(
-            ("difficulty", loss_fn, metric),
-            lambda: eng.build_difficulty_fn(loss_fn, metric),
+            ("difficulty", loss_fn, metric) + tail,
+            lambda: eng.build_difficulty_fn(loss_fn, metric, chunk),
         )
 
-    def _fim_warmup_fn(self):
+    def _fim_warmup_fn(self, chunk: Optional[int] = None):
         loss_fn, momentum, mesh = self.loss_fn, self.fl.fim_momentum, self.mesh
+        tail = () if chunk is None else (chunk,)
         if mesh is not None:
             return _memo(
-                ("fim_warmup", loss_fn, momentum, mesh),
-                lambda: eng.build_sharded_fim_warmup_fn(loss_fn, momentum, mesh),
+                ("fim_warmup", loss_fn, momentum, mesh) + tail,
+                lambda: eng.build_sharded_fim_warmup_fn(loss_fn, momentum, mesh, chunk),
             )
         return _memo(
-            ("fim_warmup", loss_fn, momentum),
-            lambda: eng.build_fim_warmup_fn(loss_fn, momentum),
+            ("fim_warmup", loss_fn, momentum) + tail,
+            lambda: eng.build_fim_warmup_fn(loss_fn, momentum, chunk),
         )
+
+    def _init_memory(self) -> Optional[tuple]:
+        """``(budget, bytes in use)`` of the init programs' device. The
+        budget is ``_init_bytes_limit`` where set (tests force chunking
+        through it), else ``memory_stats()["bytes_limit"]``; None where the
+        backend states no memory and no budget is set."""
+        dev = self.mesh.devices.flat[0] if self.mesh is not None else jax.devices()[0]
+        stats = dev.memory_stats() or {}
+        limit = self._init_bytes_limit or stats.get("bytes_limit")
+        return None if limit is None else (limit, stats.get("bytes_in_use", 0))
+
+    def _run_init_program(self, kind: str, build: Callable, args: tuple):
+        """The init-phase program ``kind`` (``build(chunk)``:
+        :meth:`_difficulty_fn` or :meth:`_fim_warmup_fn`) over every client,
+        in chunks of clients where all at once would not fit the device.
+
+        Where the device states its memory, the all-clients program is
+        compiled first; while the compiler refuses it for memory, or its
+        ``memory_analysis()`` (arguments, outputs and temporaries, plus what
+        else the device holds) exceeds the budget, it is rebuilt over half
+        as many clients at a time. The executable that fits is kept per
+        program and shapes, so later calls compile nothing, and it is the
+        one called. Where the device states no memory, the program is the
+        jitted all-clients one. The chunk taken is in ``init_chunks[kind]``
+        (None: all at once)."""
+        full, memory = build(None), self._init_memory()
+        if memory is None:
+            return full(*args)
+        limit, in_use = memory
+        shapes = tuple((tuple(x.shape), str(x.dtype)) for x in jax.tree.leaves(args))
+        key = ("init_fit", full, shapes, limit)
+        if key not in _PROGRAM_MEMO:
+            clients = jax.tree.leaves(args[1])[0].shape[0]
+            chunk, exe = None, _compile_within(full, args)
+            while (exe is None or _program_bytes(exe, in_use) > limit) and (chunk or clients) > 1:
+                chunk = max(1, (chunk or clients) // 2)
+                exe = _compile_within(build(chunk), args)
+            if exe is None:  # one client at a time does not fit either: say so
+                exe = build(chunk).lower(*args).compile()
+            _PROGRAM_MEMO[key] = (exe, chunk)
+        exe, chunk = _PROGRAM_MEMO[key]
+        self.init_chunks[kind] = chunk
+        return exe(*args)
 
     def _compress_static(self) -> Optional[Dict[str, Any]]:
         """Static compression spec baked into the round program (trace-time
@@ -830,9 +902,9 @@ class FibecFed:
         if self._stacked_engine and not self._oocore and metric in ("fisher", "loss"):
             # one program over every (client, batch) cell, each client scored
             # with its own LoRA (matters on re-init after training rounds)
-            scores = self._difficulty_fn()(
-                self.params, self._stacked_lora, self._stack_data,
-                self._sample_valid,
+            scores = self._run_init_program(
+                "difficulty", self._difficulty_fn,
+                (self.params, self._stacked_lora, self._stack_data, self._sample_valid),
             )
             with self.tel.span("difficulty_read", cat="fl", track="server"):
                 scores = np.asarray(scores)
@@ -870,7 +942,9 @@ class FibecFed:
                     wdata = jax.device_put(wdata, client_shd)
                     wsv = jax.device_put(wsv, client_shd)
             with span("fim_program", cat="fl", track="server"):
-                fims = self._fim_warmup_fn()(self.params, self._stacked_lora, wdata, wsv)
+                fims = self._run_init_program(
+                    "fim_warmup", self._fim_warmup_fn, (self.params, self._stacked_lora, wdata, wsv)
+                )
             with span("fim_select", cat="fl", track="server"):
                 importance = sparsemod.neuron_importance(fims)  # leaves (C, L, d_out)
                 if fl.sparse_ratio is not None:
@@ -1409,7 +1483,10 @@ class FibecFed:
                     self._stacked_residual = new_res
 
         with _round_phase(tel, "wait"):
+            # a held-expert MoE's program returns (losses, counts)
+            losses, counts = losses if isinstance(losses, tuple) else (losses, {})
             losses = np.asarray(losses)  # (S, lanes)
+            counts = {name: float(np.sum(c)) for name, c in counts.items()}
         with _round_phase(tel, "account"):
             valid = (step_valid if lanes is None else lanes[2]).T
             mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
@@ -1418,6 +1495,10 @@ class FibecFed:
                 "fl.rounds_unpacked" if lanes is None else "fl.rounds_packed"
             ).inc()
             runtime_metrics.histogram("fl.round_scanned_steps").observe(losses.size)
+            for name, total in counts.items():
+                # the round's (token, held expert) assignments and the expert
+                # rows its lane-steps computed
+                runtime_metrics.histogram(f"fl.moe_{name}").observe(total)
 
             self.last_round_info = {
                 "chosen": np.asarray(chosen[:k]),

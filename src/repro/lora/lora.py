@@ -3,7 +3,8 @@
 The LoRA tree mirrors the model's stacked-layer layout:
 
 - dense / moe / vlm / encoder: ``{"layers": {target: {"a": (L, d_in, r),
-  "b": (L, r, d_out)}}}`` (targets = wq/wk/wv/wo)
+  "b": (L, r, d_out)}}}`` (targets = wq/wk/wv/wo; with latent attention
+  wq/wkv_a/wkv_b/wo, one stack over every layer, leading dense ones too)
 - encdec: ``{"encoder": {...(Le)}, "decoder": {... incl. cwq..cwo (Ld)}}``
 - ssm: ``{"layers": {"in_proj"|"out_proj": {a, b}}}``
 - hybrid: ``{"mamba": stacked(L), "shared": unstacked}``
@@ -30,6 +31,10 @@ from repro.config import ModelConfig
 
 
 def _attn_dims(cfg: ModelConfig) -> Dict[str, tuple]:
+    if cfg.mla is not None:
+        from repro.models.mla import mla_dims  # lazy: breaks lora<->models cycle
+
+        return mla_dims(cfg)
     hd = cfg.resolved_head_dim
     return {
         "wq": (cfg.d_model, cfg.num_heads * hd),

@@ -51,9 +51,9 @@ def full_attention(
 ) -> jax.Array:
     """Unblocked GQA attention (used for short sequences and decode).
 
-    q: (B, Sq, H, D), k/v: (B, Sk, KVH, D). ``q_offset`` is the absolute
-    position of q[0] (for decode, Sq=1, q_offset=t). ``kv_len`` masks the
-    valid prefix of the KV cache. Returns (B, Sq, H, D).
+    q: (B, Sq, H, D), k: (B, Sk, KVH, D), v: (B, Sk, KVH, Dv). ``q_offset`` is
+    the absolute position of q[0] (for decode, Sq=1, q_offset=t). ``kv_len``
+    masks the valid prefix of the KV cache. Returns (B, Sq, H, Dv).
     """
     B, Sq, H, D = q.shape
     Sk, KVH = k.shape[1], k.shape[2]
@@ -75,7 +75,7 @@ def full_attention(
         scores = jnp.where(valid, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_out(probs, v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def blockwise_attention(
@@ -93,11 +93,12 @@ def blockwise_attention(
     """Flash-style attention: scan over KV blocks with an online softmax.
 
     Shapes as :func:`full_attention` with Sq == Sk == S (self-attention /
-    prefill). With ``window`` set, each query block only visits the KV blocks
-    inside ``[q_start - window, q_end]`` via a dynamic slice (sub-quadratic).
+    prefill); v's head size may differ from q's and k's (latent attention).
+    With ``window`` set, each query block only visits the KV blocks inside
+    ``[q_start - window, q_end]`` via a dynamic slice (sub-quadratic).
     """
     B, S, H, D = q.shape
-    KVH = k.shape[2]
+    KVH, Dv = k.shape[2], v.shape[-1]
     G = H // KVH
     if window is not None and causal and window >= S:
         # a window covering the whole sequence IS causal attention; the
@@ -148,15 +149,15 @@ def blockwise_attention(
             probs = jax.nn.softmax(scores, axis=-1).astype(score_dtype)
             return _gqa_out(probs, vb)  # (B,qb,KVH,G,D)
 
-        out = jax.lax.map(one_q_block, jnp.arange(nq))  # (nq,B,qb,KVH,G,D)
-        out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, D)
+        out = jax.lax.map(one_q_block, jnp.arange(nq))  # (nq,B,qb,KVH,G,Dv)
+        out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, Dv)
         return out
 
     # full/causal: online softmax over all KV blocks
     assert S % kv_block == 0
     nk = S // kv_block
     kb_all = k.reshape(B, nk, kv_block, KVH, D)
-    vb_all = v.reshape(B, nk, kv_block, KVH, D)
+    vb_all = v.reshape(B, nk, kv_block, KVH, Dv)
 
     def one_q_block(qi):
         qb = qg[:, qi]  # (B,qb,KVH,G,D)
@@ -186,13 +187,13 @@ def blockwise_attention(
 
         m0 = jnp.full((B, KVH, G, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KVH, G, q_block), jnp.float32)
-        acc0 = jnp.zeros((B, KVH, G, q_block, D), jnp.float32)
+        acc0 = jnp.zeros((B, KVH, G, q_block, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, acc0), jnp.arange(nk))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return jnp.moveaxis(out, 3, 1).astype(q.dtype)  # (B,qb,KVH,G,D)
 
-    out = jax.lax.map(one_q_block, jnp.arange(nq))  # (nq,B,qb,KVH,G,D)
-    out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, D)
+    out = jax.lax.map(one_q_block, jnp.arange(nq))  # (nq,B,qb,KVH,G,Dv)
+    out = jnp.moveaxis(out, 0, 1).reshape(B, S, H, Dv)
     return out
 
 

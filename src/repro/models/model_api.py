@@ -50,6 +50,11 @@ class ModelFns:
     decode_step: Callable[..., Any]
     input_specs: Callable[[InputShape], Dict[str, Any]]
     supports: Callable[[InputShape], bool]
+    # (params, lora, batch) -> (logits, aux, stats): ``forward`` plus the
+    # held-expert layers' counts (``{"held_assignments", "expert_rows"}``
+    # scalars, assignments weighted by ``batch["sample_mask"]``); None where
+    # the model keeps none
+    forward_stats: Optional[Callable[..., Any]] = None
 
 
 def _token_dtype():
@@ -124,6 +129,12 @@ def _decoder_fns(cfg: ModelConfig) -> ModelFns:
             embed_noise=embed_noise, collect_layer_norms=True,
         )
 
+    def forward_stats(params, lora, batch):
+        return _tf.decoder_forward(
+            params, lora["layers"], batch["tokens"], cfg,
+            sample_weight=batch.get("sample_mask"), with_stats=True,
+        )
+
     def init_cache(batch, cache_len):
         return _tf.init_kv_cache(cfg, batch, cache_len)
 
@@ -152,6 +163,7 @@ def _decoder_fns(cfg: ModelConfig) -> ModelFns:
         decode_step=decode_step,
         input_specs=_make_input_specs(cfg),
         supports=_make_supports(cfg),
+        forward_stats=forward_stats if _tf.keeps_expert_stats(cfg) else None,
     )
 
 

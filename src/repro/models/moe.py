@@ -1,20 +1,28 @@
-"""Mixture-of-Experts with capacity-based grouped dispatch (expert parallel).
+"""Mixture-of-Experts layers.
 
-Tokens are routed within *groups* of ``router_group_size`` tokens so the
-dispatch one-hot tensor stays small: capacity per expert per group is
-``ceil(G * top_k * cf / E)``. Dispatch/combine are einsums against a
-``(B, n_groups, G, E, C)`` mask — under pjit with experts sharded on the
-``model`` axis and tokens on ``data`` this lowers to the canonical
-all-to-all expert-parallel schedule (MaxText-style "dropping" strategy).
+Two routers (``MoEConfig.router``):
 
-The routed experts are part of the *frozen base model* for FibecFed (LoRA is
-applied to attention + the shared expert); the router itself is frozen too.
-Aux load-balance loss is returned for training-mode monitoring.
+- ``"softmax"``: capacity-based grouped dispatch (expert parallel). Tokens
+  are routed within *groups* of ``router_group_size`` tokens so the
+  dispatch one-hot tensor stays small: capacity per expert per group is
+  ``ceil(G * top_k * cf / E)``. Dispatch/combine are einsums against a
+  ``(B, n_groups, G, E, C)`` mask — under pjit with experts sharded on the
+  ``model`` axis and tokens on ``data`` this lowers to the canonical
+  all-to-all expert-parallel schedule (MaxText-style "dropping" strategy).
+  Aux load-balance loss is returned for training-mode monitoring.
+- ``"sigmoid_bias"`` (DeepSeek-V3, :func:`apply_held_moe`): top-k of sigmoid
+  scores plus a frozen correction bias, dropless, over a layer that holds
+  only experts ``0..held-1`` of the router's ``num_experts`` (a chip's share
+  of an expert-parallel split) and computes their part of the result.
+
+The routed experts, the shared expert and the router are all part of the
+*frozen base model* for FibecFed: LoRA trains the attention projections
+only (``repro.lora``).
 """
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,23 +32,29 @@ from repro.models.layers import init_stacked_dense
 
 
 def init_moe(rng, n_layers: int, d_model: int, mcfg: MoEConfig, dtype):
+    """Stacked router and expert weights. The sigmoid router adds its
+    correction bias (float32, zero) and keeps only the held experts'
+    weights."""
     r = jax.random.split(rng, 7)
     E, Fe = mcfg.num_experts, mcfg.d_ff_expert
+    held = mcfg.held if mcfg.router == "sigmoid_bias" else E
     p = {
         "router": init_stacked_dense(r[0], n_layers, d_model, E, dtype, scale=0.02),
         "e_gate": (
-            jax.random.normal(r[1], (n_layers, E, d_model, Fe), jnp.float32)
+            jax.random.normal(r[1], (n_layers, held, d_model, Fe), jnp.float32)
             / math.sqrt(d_model)
         ).astype(dtype),
         "e_up": (
-            jax.random.normal(r[2], (n_layers, E, d_model, Fe), jnp.float32)
+            jax.random.normal(r[2], (n_layers, held, d_model, Fe), jnp.float32)
             / math.sqrt(d_model)
         ).astype(dtype),
         "e_down": (
-            jax.random.normal(r[3], (n_layers, E, Fe, d_model), jnp.float32)
+            jax.random.normal(r[3], (n_layers, held, Fe, d_model), jnp.float32)
             / math.sqrt(Fe)
         ).astype(dtype),
     }
+    if mcfg.router == "sigmoid_bias":
+        p["router_bias"] = jnp.zeros((n_layers, E), jnp.float32)
     if mcfg.shared_expert:
         Fs = mcfg.d_ff_shared
         p["s_gate"] = init_stacked_dense(r[4], n_layers, d_model, Fs, dtype)
@@ -153,3 +167,74 @@ def apply_moe(
         h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
         y = y + jnp.einsum("bsf,fd->bsd", h, p["s_down"])
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# sigmoid router with a correction bias, over a layer of held experts
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid(x: jax.Array, router_w: jax.Array, bias: jax.Array, mcfg: MoEConfig):
+    """DeepSeek-V3's ``noaux_tc`` router (one expert group). x: (..., D).
+
+    Returns ``(idx, weight)``, each (..., top_k): the top-k experts of
+    ``sigmoid(x W_r) + bias`` and their *unbiased* sigmoid scores,
+    normalised over the k and scaled by ``routed_scale``.
+    Scores are computed in float32 at full precision: the selection is
+    discrete, and a rounded score flips it."""
+    logits = jnp.einsum(
+        "...d,de->...e", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), mcfg.top_k)
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, weight / jnp.sum(weight, axis=-1, keepdims=True) * mcfg.routed_scale
+
+
+def apply_held_moe(
+    x: jax.Array,
+    p,
+    mcfg: MoEConfig,
+    *,
+    sample_weight: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The sigmoid-routed expert layer of a chip that holds experts
+    ``0..held-1``. x: (B, S, D); p the per-layer slice. Returns ``(y,
+    counts)``.
+
+    The router scores all ``num_experts``; every (token, expert) assignment
+    that lands on a held expert is computed, whatever the skew (dropless),
+    and the absent experts contribute nothing. The held experts run densely
+    over every token with a gate that is zero where the token did not pick
+    them (``B * S * held`` expert rows): at a chip's share of tokens an
+    expert sees tens of tokens, and this keeps the layer dense matmuls under
+    the client ``vmap`` and ``grad``. The shared expert then runs on every
+    token. ``counts`` (float32 scalars, no gradient): ``held_assignments``,
+    the (token, held expert) assignments, each sample weighted by
+    ``sample_weight`` (B,) when given, and ``expert_rows``, the rows the held
+    experts computed."""
+    held = mcfg.held
+    with jax.named_scope("router"):
+        idx, weight = route_sigmoid(x, p["router"], p["router_bias"], mcfg)  # (B,S,K)
+        onehot = jax.nn.one_hot(idx, held, dtype=jnp.float32)  # (B,S,K,held)
+        gate = jnp.einsum("bske,bsk->bse", onehot, weight)  # picked: weight; else 0
+        per_sample = jnp.sum(onehot, axis=(1, 2, 3))  # (B,)
+        if sample_weight is not None:
+            per_sample = per_sample * sample_weight.astype(jnp.float32)
+        count = jax.lax.stop_gradient(jnp.sum(per_sample))
+    with jax.named_scope("routed_experts"):
+        # in the model's dtype, as the dense SwiGLU: what the backward pass
+        # keeps of (B, S, held, d_ff_expert) is most of a step's memory
+        g = jnp.einsum("bsd,edf->bsef", x, p["e_gate"])
+        u = jnp.einsum("bsd,edf->bsef", x, p["e_up"])
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        y = jnp.einsum("bsef,efd->bsd", h * gate[..., None].astype(x.dtype), p["e_down"])
+    if mcfg.shared_expert:
+        with jax.named_scope("shared_experts"):
+            g = jnp.einsum("bsd,df->bsf", x, p["s_gate"])
+            u = jnp.einsum("bsd,df->bsf", x, p["s_up"])
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+            y = y + jnp.einsum("bsf,fd->bsd", h, p["s_down"])
+    rows = jnp.float32(x.shape[0] * x.shape[1] * held)
+    return y, {"held_assignments": count, "expert_rows": rows}

@@ -8,7 +8,9 @@ layout; the scan consumes (param_slice, lora_slice[, cache_slice]) per step.
 Supported knobs (ModelConfig): GQA ratios, qkv bias (qwen2), qk-norm (qwen3),
 RoPE full/half ("2d", chatglm), parallel residual, rms/layer norm, SwiGLU/GELU
 MLP, MoE FFN (+shared expert), sliding-window attention, prefix embeddings
-(paligemma patches / audio frames), logit soft-cap, tied embeddings.
+(paligemma patches / audio frames), logit soft-cap, tied embeddings; and the
+DeepSeek-V3 block (Moonlight): latent attention (``models.mla``), leading
+dense layers, then sigmoid-routed held experts (``moe.apply_held_moe``).
 """
 from __future__ import annotations
 
@@ -28,8 +30,9 @@ from repro.models.layers import (
     layer_norm,
     soft_cap,
 )
+from repro.models.mla import init_mla_stack, mla_attention
 from repro.models.mlp import apply_mlp, init_mlp
-from repro.models.moe import apply_moe, init_moe
+from repro.models.moe import apply_held_moe, apply_moe, init_moe
 
 LORA_ATTN_TARGETS = ("wq", "wk", "wv", "wo")
 
@@ -69,6 +72,8 @@ def _init_norms(n_layers: int, d: int, kind: str, dtype, names) -> Dict[str, Any
 
 
 def init_decoder(rng, cfg: ModelConfig) -> Dict[str, Any]:
+    if cfg.mla is not None:
+        return init_mla_decoder(rng, cfg)
     dtype = jnp.dtype(cfg.dtype)
     r = jax.random.split(rng, 6)
     L = cfg.num_layers
@@ -112,15 +117,47 @@ def init_lora_attn(rng, n_layers: int, cfg: ModelConfig, targets=LORA_ATTN_TARGE
     return out
 
 
+def _mla_dense_layers(cfg: ModelConfig) -> int:
+    """Leading layers of an MLA decoder that run a dense SwiGLU (all of them
+    without experts)."""
+    return cfg.num_layers if cfg.moe is None else cfg.moe.first_dense_layers
+
+
+def init_mla_decoder(rng, cfg: ModelConfig) -> Dict[str, Any]:
+    """An MLA decoder (DeepSeek-V3 layout): ``dense_layers`` stacks the
+    leading layers (MLA + a SwiGLU of width ``d_ff``), ``layers`` the expert
+    layers (MLA + the sigmoid-routed experts and the shared expert); an
+    untied head unless ``tie_embeddings``."""
+    dtype = jnp.dtype(cfg.dtype)
+    n0 = _mla_dense_layers(cfg)
+    r = jax.random.split(rng, 6)
+    params: Dict[str, Any] = {"embed": init_embed(r[0], cfg.vocab_size, cfg.d_model, dtype)}
+    stacks = (("dense_layers", n0, r[1], r[2]), ("layers", cfg.num_layers - n0, r[3], r[4]))
+    for name, n, r_attn, r_ffn in stacks:
+        if not n:
+            continue
+        stack = init_mla_stack(r_attn, n, cfg, dtype)
+        stack.update(_init_norms(n, cfg.d_model, cfg.norm, dtype, ["attn_norm", "mlp_norm"]))
+        if name == "dense_layers":
+            stack.update(init_mlp(r_ffn, n, cfg.d_model, cfg.d_ff, "swiglu", dtype))
+        else:
+            stack.update(init_moe(r_ffn, n, cfg.d_model, cfg.moe, dtype))
+        params[name] = stack
+    params["final_norm_w"] = jnp.ones((cfg.d_model,), dtype)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = init_stacked_dense(r[5], 1, cfg.d_model, cfg.vocab_size, dtype)[0]
+    return params
+
+
 # ---------------------------------------------------------------------------
 # layer body
 # ---------------------------------------------------------------------------
 
 
-def _norm(h, p, name, kind):
+def _norm(h, p, name, kind, eps: float = 1e-6):
     if kind == "layernorm":
         return layer_norm(h, p[f"{name}_w"], p[f"{name}_b"])
-    return rms_norm(h, p[f"{name}_w"])
+    return rms_norm(h, p[f"{name}_w"], eps)
 
 
 def _project_qkv(x, p, lora, cfg: ModelConfig, lora_scale):
@@ -135,8 +172,8 @@ def _project_qkv(x, p, lora, cfg: ModelConfig, lora_scale):
     k = k.reshape(B, S, cfg.num_kv_heads, hd)
     v = v.reshape(B, S, cfg.num_kv_heads, hd)
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm_w"])
-        k = rms_norm(k, p["k_norm_w"])
+        q = rms_norm(q, p["q_norm_w"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm_w"], cfg.norm_eps)
     return q, k, v
 
 
@@ -201,7 +238,7 @@ def decoder_layer(
     Its ops carry the named scopes ``attention`` and ``mlp`` (norm and
     residual add included), which label them in a profile."""
     with jax.named_scope("attention"):
-        x = _norm(h, p, "attn_norm", cfg.norm)
+        x = _norm(h, p, "attn_norm", cfg.norm, cfg.norm_eps)
         attn_out, new_cache = attention_sublayer(
             x, p, lora, cfg, positions, lora_scale=lora_scale, causal=causal,
             cache=cache, cache_position=cache_position, ring=ring,
@@ -214,7 +251,7 @@ def decoder_layer(
         with jax.named_scope("attention"):
             h = h + attn_out
         with jax.named_scope("mlp"):
-            x2 = _norm(h, p, "mlp_norm", cfg.norm)
+            x2 = _norm(h, p, "mlp_norm", cfg.norm, cfg.norm_eps)
             mlp_out, aux = _ffn(x2, p, cfg, lora, lora_scale, sample_weight)
             h = h + mlp_out
     return h, aux, new_cache
@@ -239,7 +276,7 @@ def _lm_logits(h, params, cfg: ModelConfig):
     if cfg.norm == "layernorm":
         h = layer_norm(h, params["final_norm_w"], params["final_norm_b"])
     else:
-        h = rms_norm(h, params["final_norm_w"])
+        h = rms_norm(h, params["final_norm_w"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))
     return soft_cap(logits, cfg.logit_soft_cap)
@@ -256,6 +293,7 @@ def decoder_forward(
     embed_noise: Optional[jax.Array] = None,
     collect_layer_norms: bool = False,
     sample_weight: Optional[jax.Array] = None,
+    with_stats: bool = False,
 ):
     """Training/eval forward. Returns (logits (B, S_total, V), aux_loss).
 
@@ -265,8 +303,19 @@ def decoder_forward(
     hidden states are returned as a third output (num_layers, B).
     ``sample_weight`` (B,) restricts the MoE load-balance aux loss to valid
     samples (padded-batch training); logits are unaffected.
+    An MLA decoder (``cfg.mla``) takes :func:`_mla_decoder_forward`; with
+    ``with_stats`` it also returns the counts of its held-expert layers
+    (:func:`keeps_expert_stats`): ``held_assignments``, the (token, held
+    expert) assignments, each sample weighted by ``sample_weight``, and
+    ``expert_rows``, the expert rows those layers computed.
     """
     lora_scale = lora_scale if lora_scale is not None else cfg.lora_alpha / cfg.lora_rank
+    if cfg.mla is not None:
+        return _mla_decoder_forward(
+            params, lora, tokens, cfg, lora_scale=lora_scale, embed_noise=embed_noise,
+            collect_layer_norms=collect_layer_norms, sample_weight=sample_weight,
+            with_stats=with_stats,
+        )
     h = _embed_inputs(params, tokens, cfg, prefix_embeds)
     if embed_noise is not None:
         h = h + embed_noise.astype(h.dtype)
@@ -305,7 +354,90 @@ def decoder_forward(
     return logits, aux
 
 
+def keeps_expert_stats(cfg: ModelConfig) -> bool:
+    """Whether the model has held-expert layers, whose counts
+    ``decoder_forward(..., with_stats=True)`` returns: the sigmoid router's
+    layers (:func:`apply_held_moe`)."""
+    return cfg.moe is not None and cfg.moe.router == "sigmoid_bias"
+
+
+def _no_counts():
+    return {k: jnp.zeros((), jnp.float32) for k in ("held_assignments", "expert_rows")}
+
+
+def _mla_layer(h, p, lora, cfg: ModelConfig, positions, *, lora_scale, experts: bool,
+               sample_weight=None):
+    """One MLA block: attention (scopes ``attention`` > ``mla``), then the
+    sigmoid-routed experts (``mlp`` > ``router``, ``routed_experts``,
+    ``shared_experts``) or a dense SwiGLU (``dense_ffn``). Returns ``(h,
+    counts)``, :func:`apply_held_moe`'s counts, 0 in a dense layer."""
+    with jax.named_scope("attention"):
+        x = rms_norm(h, p["attn_norm_w"], cfg.norm_eps)
+        h = h + mla_attention(x, p, lora, cfg, positions, lora_scale=lora_scale)
+    if experts:
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, p["mlp_norm_w"], cfg.norm_eps)
+            y, counts = apply_held_moe(x, p, cfg.moe, sample_weight=sample_weight)
+            return h + y, counts
+    with jax.named_scope("dense_ffn"):
+        x = rms_norm(h, p["mlp_norm_w"], cfg.norm_eps)
+        return h + apply_mlp(x, p, "swiglu"), _no_counts()
+
+
+def _mla_decoder_forward(params, lora, tokens, cfg: ModelConfig, *, lora_scale,
+                         embed_noise=None, collect_layer_norms=False, sample_weight=None,
+                         with_stats=False):
+    """:func:`decoder_forward` of an MLA decoder: a scan over the leading
+    dense layers with ``lora[:n0]``, then a scan over the expert layers with
+    ``lora[n0:]`` (the LoRA tree is one stack over every layer)."""
+    h = _embed_inputs(params, tokens, cfg)
+    if embed_noise is not None:
+        h = h + embed_noise.astype(h.dtype)
+    positions = jnp.arange(h.shape[1])[None, :]
+    n0 = _mla_dense_layers(cfg)
+    counts = _no_counts()
+    norms = []
+    for name, lo, experts in (
+        ("dense_layers", jax.tree.map(lambda x: x[:n0], lora), False),
+        ("layers", jax.tree.map(lambda x: x[n0:], lora), cfg.moe is not None),
+    ):
+        if name not in params:
+            continue
+
+        def layer_fn(h, p_slice, lora_slice, experts=experts):
+            return _mla_layer(h, p_slice, lora_slice, cfg, positions, lora_scale=lora_scale,
+                              experts=experts, sample_weight=sample_weight)
+
+        if cfg.remat:
+            layer_fn = jax.checkpoint(layer_fn)
+
+        def body(carry, xs, layer_fn=layer_fn):
+            h, c = carry
+            h, c_l = layer_fn(h, *xs)
+            norm = jnp.sqrt(jnp.sum(jnp.square(h.astype(jnp.float32)), axis=(1, 2)))
+            return (h, jax.tree.map(jnp.add, c, c_l)), (norm if collect_layer_norms else None)
+
+        (h, counts), n = jax.lax.scan(body, (h, counts), (params[name], lo))
+        norms.append(n)
+    logits = _lm_logits(h, params, cfg)
+    aux = jnp.zeros((), jnp.float32)  # sigmoid routing balances by its bias: no aux loss
+    if collect_layer_norms:
+        return logits, aux, jnp.concatenate(norms)
+    if with_stats:
+        return logits, aux, counts
+    return logits, aux
+
+
+def _refuse_mla(cfg: ModelConfig) -> None:
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no KV cache here; serving an MLA "
+            "model (prefill, decode) is not supported"
+        )
+
+
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
+    _refuse_mla(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     hd = cfg.resolved_head_dim
     shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, hd)
@@ -317,6 +449,7 @@ def decoder_prefill(
     *, prefix_embeds=None, lora_scale=None,
 ):
     """Run the prompt, fill the KV cache. Returns (last_logits, cache, pos)."""
+    _refuse_mla(cfg)
     lora_scale = lora_scale if lora_scale is not None else cfg.lora_alpha / cfg.lora_rank
     h = _embed_inputs(params, tokens, cfg, prefix_embeds)
     B, S = h.shape[0], h.shape[1]
@@ -325,7 +458,7 @@ def decoder_prefill(
 
     def body(h, xs):
         p_slice, lora_slice = xs
-        x = _norm(h, p_slice, "attn_norm", cfg.norm)
+        x = _norm(h, p_slice, "attn_norm", cfg.norm, cfg.norm_eps)
         q, k, v = _project_qkv(x, p_slice, lora_slice, cfg, lora_scale)
         q = apply_rope(q, positions, theta=cfg.rope_theta, mode=cfg.rope)
         k = apply_rope(k, positions, theta=cfg.rope_theta, mode=cfg.rope)
@@ -333,7 +466,7 @@ def decoder_prefill(
         o = o.reshape(B, S, cfg.num_heads * cfg.resolved_head_dim)
         lget = (lambda kk: lora_slice.get(kk) if lora_slice else None)
         h = h + linear(o, {"w": p_slice["wo"]}, lget("wo"), lora_scale)
-        x2 = _norm(h, p_slice, "mlp_norm", cfg.norm)
+        x2 = _norm(h, p_slice, "mlp_norm", cfg.norm, cfg.norm_eps)
         mlp_out, _ = _ffn(x2, p_slice, cfg, lora_slice, lora_scale)
         h = h + mlp_out
         # keep the cache tail (last cache_len positions fit by construction)
@@ -362,6 +495,7 @@ def decoder_decode_step(
 ):
     """One-token step. token: (B, 1) int32; ``position`` scalar (uniform
     batch) or (B,) per-slot positions. Returns (logits, new_cache)."""
+    _refuse_mla(cfg)
     lora_scale = lora_scale if lora_scale is not None else cfg.lora_alpha / cfg.lora_rank
     h = jnp.take(params["embed"], token, axis=0)
     if cfg.family == "vlm":

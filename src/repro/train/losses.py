@@ -54,6 +54,9 @@ def make_loss_fn(model: ModelFns) -> Callable:
     batches at full batched-matmul efficiency. The MoE load-balance aux term
     is mask-aware too: the mask is threaded to the router as a per-sample
     weight, so padded and ragged batches produce identical aux losses.
+    A model with ``forward_stats`` (the held-expert MoE) also gets
+    ``.masked_stats(params, lora, batch, sample_mask) -> (loss, stats)`` for
+    the label-token objective, the counts weighted by the mask.
     """
     cached = _LOSS_FN_CACHE.get(id(model))
     if cached is not None:
@@ -70,10 +73,7 @@ def make_loss_fn(model: ModelFns) -> Callable:
             offset = cfg.num_prefix_embeddings if cfg.family == "vlm" else 0
             return lm_loss(logits, batch["tokens"], offset) + aux
 
-    def masked(params, lora, batch: Dict[str, Any], sample_mask):
-        if cfg.family == "moe":
-            batch = dict(batch, sample_mask=sample_mask)
-        logits, aux = model.forward(params, lora, batch)
+    def masked_mean(logits, aux, batch, sample_mask):
         with jax.named_scope("loss"):
             m = sample_mask.astype(jnp.float32)
             denom = jnp.maximum(jnp.sum(m), 1.0)
@@ -88,7 +88,23 @@ def make_loss_fn(model: ModelFns) -> Callable:
                 per = jnp.mean(_xent(pred, tokens[:, 1:]), axis=-1)
             return jnp.sum(per * m) / denom + aux
 
+    def masked(params, lora, batch: Dict[str, Any], sample_mask):
+        if cfg.family == "moe":
+            batch = dict(batch, sample_mask=sample_mask)
+        logits, aux = model.forward(params, lora, batch)
+        return masked_mean(logits, aux, batch, sample_mask)
+
     loss_fn.masked = masked
+    if model.forward_stats is not None:
+
+        def masked_stats(params, lora, batch: Dict[str, Any], sample_mask):
+            """``masked``'s loss and the forward's counts over the valid
+            samples: ``(loss, stats)``."""
+            batch = dict(batch, sample_mask=sample_mask)
+            logits, aux, stats = model.forward_stats(params, lora, batch)
+            return masked_mean(logits, aux, batch, sample_mask), stats
+
+        loss_fn.masked_stats = masked_stats
     # hold the model ref so id() stays unique for the cache's lifetime
     loss_fn._model = model
     _LOSS_FN_CACHE[id(model)] = loss_fn
