@@ -1496,8 +1496,9 @@ class FibecFed:
             ).inc()
             runtime_metrics.histogram("fl.round_scanned_steps").observe(losses.size)
             for name, total in counts.items():
-                # the round's (token, held expert) assignments and the expert
-                # rows its lane-steps computed
+                # the round's (token, held expert) assignments, the expert
+                # rows its lane-steps computed and the held experts they ran
+                # over every token
                 runtime_metrics.histogram(f"fl.moe_{name}").observe(total)
 
             self.last_round_info = {

@@ -51,9 +51,9 @@ class ModelFns:
     input_specs: Callable[[InputShape], Dict[str, Any]]
     supports: Callable[[InputShape], bool]
     # (params, lora, batch) -> (logits, aux, stats): ``forward`` plus the
-    # held-expert layers' counts (``{"held_assignments", "expert_rows"}``
-    # scalars, assignments weighted by ``batch["sample_mask"]``); None where
-    # the model keeps none
+    # held-expert layers' counts (``{"held_assignments", "expert_rows",
+    # "dense_fallbacks"}`` scalars, assignments weighted by
+    # ``batch["sample_mask"]``); None where the model keeps none
     forward_stats: Optional[Callable[..., Any]] = None
 
 

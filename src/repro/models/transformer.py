@@ -306,8 +306,9 @@ def decoder_forward(
     An MLA decoder (``cfg.mla``) takes :func:`_mla_decoder_forward`; with
     ``with_stats`` it also returns the counts of its held-expert layers
     (:func:`keeps_expert_stats`): ``held_assignments``, the (token, held
-    expert) assignments, each sample weighted by ``sample_weight``, and
-    ``expert_rows``, the expert rows those layers computed.
+    expert) assignments, each sample weighted by ``sample_weight``,
+    ``expert_rows``, the expert rows those layers computed, and
+    ``dense_fallbacks``, the held experts those layers ran over every token.
     """
     lora_scale = lora_scale if lora_scale is not None else cfg.lora_alpha / cfg.lora_rank
     if cfg.mla is not None:
@@ -362,7 +363,8 @@ def keeps_expert_stats(cfg: ModelConfig) -> bool:
 
 
 def _no_counts():
-    return {k: jnp.zeros((), jnp.float32) for k in ("held_assignments", "expert_rows")}
+    return {k: jnp.zeros((), jnp.float32)
+            for k in ("held_assignments", "expert_rows", "dense_fallbacks")}
 
 
 def _mla_layer(h, p, lora, cfg: ModelConfig, positions, *, lora_scale, experts: bool,

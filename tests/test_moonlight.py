@@ -28,7 +28,9 @@ from repro.config import FibecFedConfig  # noqa: E402
 from repro.configs import ARCHS  # noqa: E402
 from repro.federated import make_runner  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.models.moe import apply_held_moe, route_sigmoid  # noqa: E402
+from repro.models.moe import (  # noqa: E402
+    apply_held_moe, expert_capacities, route_sigmoid,
+)
 from repro.obs import runtime_metrics  # noqa: E402
 from repro.train import make_loss_fn  # noqa: E402
 
@@ -162,7 +164,154 @@ def test_the_layer_is_dropless_when_every_token_picks_one_held_expert():
     want, n = m.experts(prec, x, w)  # the residual block: x + its feed-forward
     np.testing.assert_allclose(np.asarray(y), np.asarray(want - x), rtol=RTOL, atol=1e-6)
     assert float(counts["held_assignments"]) == float(n[0]) == x.shape[1]  # every token, once
-    assert float(counts["expert_rows"]) == x.shape[0] * x.shape[1] * 2  # both held, every token
+    # held expert 0 over every token; held expert 1, picked by none, over no row
+    assert float(counts["expert_rows"]) == x.shape[0] * x.shape[1]
+    assert float(counts["dense_fallbacks"]) == 1.0
+
+
+def _bias_for(w, x, mcfg, above: int, at_most: int):
+    """A correction bias under which held expert 0 takes more than ``above``
+    and at most ``at_most`` of the tokens of ``x``, and held expert 1 none:
+    the largest held load, by bisection on expert 0's bias."""
+    base = w["router_bias"].at[1].set(-50.0)
+    lo, hi = -50.0, 50.0
+    for _ in range(60):
+        mid = (lo + hi) / 2
+        idx, _ = route_sigmoid(x, w["router"], base.at[0].set(mid), mcfg)
+        load = int(jnp.sum(idx == 0))
+        if above < load <= at_most:
+            return base.at[0].set(mid)
+        lo, hi = (mid, hi) if load <= above else (lo, mid)
+    raise AssertionError(f"no bias puts expert 0's load in ({above}, {at_most}]")
+
+
+def _dense_experts(x, p, mcfg):
+    """The held experts over every token, gated (the layer before the
+    ladder, and the ladder's last branch for every expert)."""
+    idx, weight = route_sigmoid(x, p["router"], p["router_bias"], mcfg)
+    gate = jnp.einsum("bske,bsk->bse", jax.nn.one_hot(idx, mcfg.held, dtype=jnp.float32), weight)
+    g = jnp.einsum("bsd,edf->bsef", x, p["e_gate"])
+    u = jnp.einsum("bsd,edf->bsef", x, p["e_up"])
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    return jnp.einsum("bsef,efd->bsd", h * gate[..., None].astype(x.dtype), p["e_down"])
+
+
+def _value_and_input_grad(f, x, seed=5):
+    """``f(x)`` and the gradient of its product with a fixed random
+    cotangent w.r.t. ``x`` (through the expert rows and the gates), in
+    float32."""
+    y, vjp = jax.vjp(f, x)
+    ct = jax.random.normal(jax.random.PRNGKey(seed), y.shape).astype(y.dtype)
+    return y.astype(jnp.float32), vjp(ct)[0].astype(jnp.float32)
+
+
+T_LADDER = 256  # Moonlight's tokens per lane (batch 2 x 128): rungs 32, 64, 128
+LOADS = (0, 32, 64, 128, T_LADDER)  # branch i takes a load in (LOADS[i], LOADS[i + 1]]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("branch", [0, 1, 2, 3], ids=["rung32", "rung64", "rung128", "dense"])
+def test_each_rung_and_the_dense_fallback_match_the_dense_layer(branch, dtype):
+    """Each branch of the capacity ladder against the held experts run
+    densely, forced by the correction bias (held expert 0 at a load of the
+    branch, held expert 1 picked by no token: no rows). In float32 they
+    differ by the order of sums alone; in bfloat16 the ladder is no further
+    from the float32 layer than the dense layer in bfloat16 is (both round;
+    the ladder sums the experts' outputs in float32 and rounds once, as the
+    dense contraction does)."""
+    assert expert_capacities(T_LADDER) == LOADS[1:-1]
+    mcfg = _mcfg(2, shared_expert=False)
+    w, x = _layer(tiny_config(), 2, T=T_LADDER)
+    dt = jnp.dtype(dtype)
+    w = {k: v if k == "router_bias" else v.astype(dt) for k, v in w.items()}
+    x = x.astype(dt)
+    w["router_bias"] = _bias_for(w, x, mcfg, LOADS[branch], LOADS[branch + 1])
+    counts = apply_held_moe(x, w, mcfg)[1]
+    assert float(counts["expert_rows"]) == LOADS[branch + 1]  # expert 0's C (or T), expert 1's 0
+    assert float(counts["dense_fallbacks"]) == float(branch == 3)
+    y, g = _value_and_input_grad(lambda x: apply_held_moe(x, w, mcfg)[0], x)
+    yd, gd = _value_and_input_grad(lambda x: _dense_experts(x, w, mcfg), x)
+    if dtype == "float32":
+        assert _rel(y, yd) < RTOL and _rel(g, gd) < RTOL, (_rel(y, yd), _rel(g, gd))
+        return
+    w32 = jax.tree.map(lambda v: v.astype(jnp.float32), w)
+    y32, g32 = _value_and_input_grad(lambda x: _dense_experts(x, w32, mcfg), x.astype(jnp.float32))
+    for got, dense, want in ((y, yd, y32), (g, gd, g32)):
+        assert 0 < _rel(dense, want) and _rel(got, want) <= 1.1 * _rel(dense, want), (
+            _rel(got, want), _rel(dense, want))
+
+
+def test_every_lane_takes_the_rung_of_the_heaviest_lane():
+    """Under ``vmap``, three lanes whose loads alone would take three rungs
+    (one of them no rows) all take the heaviest lane's, and each matches the
+    dense layer."""
+    mcfg = _mcfg(2, shared_expert=False)
+    w, heavy = _layer(tiny_config(), 2, T=T_LADDER)
+    w["router_bias"] = _bias_for(w, heavy, mcfg, 64, 128)
+    idx, _ = route_sigmoid(heavy, w["router"], w["router_bias"], mcfg)
+    idle = heavy[:, int(jnp.argmin(jnp.any(idx[0] == 0, axis=-1)))]  # a token on no held expert
+    light = heavy.at[:, T_LADDER // 2:].set(idle)  # about half the heavy lane's load
+    lanes = jnp.stack([light, heavy, jnp.broadcast_to(idle, heavy.shape)])  # (3, 1, T, D)
+    alone = [float(apply_held_moe(lane, w, mcfg)[1]["expert_rows"]) for lane in lanes]
+    assert alone == [64.0, 128.0, 0.0], alone
+    y, counts = jax.vmap(lambda x: apply_held_moe(x, w, mcfg))(lanes)
+    np.testing.assert_array_equal(np.asarray(counts["expert_rows"]), [alone[1]] * 3)
+    np.testing.assert_array_equal(np.asarray(counts["dense_fallbacks"]), [0.0] * 3)
+    y, g = jax.vmap(lambda x: _value_and_input_grad(lambda x: apply_held_moe(x, w, mcfg)[0], x))(
+        lanes)
+    for i, lane in enumerate(lanes[:2]):  # the idle lane's layer output is all zeros
+        yd, gd = _value_and_input_grad(lambda x: _dense_experts(x, w, mcfg), lane)
+        assert _rel(y[i], yd) < RTOL and _rel(g[i], gd) < RTOL, (i, _rel(y[i], yd), _rel(g[i], gd))
+    assert not jnp.any(y[2]) and not jnp.any(g[2])
+
+
+@pytest.mark.parametrize("branch", [1, 3], ids=["rung64", "dense"])
+def test_the_vmapped_remat_gradient_keeps_the_ladder_a_conditional(branch):
+    """``jit(vmap(grad(checkpoint(layer))))`` compiles the ladder to HLO
+    ``conditional``s, not selects that run every branch, and the counts say
+    which branch ran."""
+    mcfg = _mcfg(2)
+    w, x = _layer(tiny_config(), 2, T=T_LADDER)
+    w["router_bias"] = _bias_for(w, x, mcfg, LOADS[branch], LOADS[branch + 1])
+
+    def f(x):
+        y, counts = apply_held_moe(x, w, mcfg)
+        return jnp.sum(jnp.square(y)), counts
+
+    fn = jax.jit(jax.vmap(jax.grad(jax.checkpoint(f), has_aux=True)))
+    lanes = jnp.stack([x, x[:, ::-1]])
+    hlo = fn.lower(lanes).compile().as_text()
+    assert hlo.count(" conditional(") >= 2 * mcfg.held  # a forward and a backward switch per expert
+    _, counts = fn(lanes)
+    np.testing.assert_array_equal(np.asarray(counts["expert_rows"]), [float(LOADS[branch + 1])] * 2)
+    np.testing.assert_array_equal(np.asarray(counts["dense_fallbacks"]), [float(branch == 3)] * 2)
+
+
+@pytest.mark.parametrize("branch", [0, 3], ids=["rung32", "dense"])
+def test_the_hessian_vector_product_matches_the_dense_layer(branch):
+    """Forward over reverse (as ``gal.make_lora_hvp``): the custom gradient
+    is differentiated as plain code, and meets the dense layer's."""
+    mcfg = _mcfg(2, shared_expert=False)
+    w, x = _layer(tiny_config(), 2, T=T_LADDER)
+    w["router_bias"] = _bias_for(w, x, mcfg, LOADS[branch], LOADS[branch + 1])
+    v = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+
+    def hvp(layer):
+        grad = jax.grad(lambda x: jnp.sum(jnp.square(layer(x))))
+        return jax.jvp(grad, (x,), (v,))[1]
+
+    got = hvp(lambda x: apply_held_moe(x, w, mcfg)[0])
+    want = hvp(lambda x: _dense_experts(x, w, mcfg))
+    assert _rel(got, want) < RTOL, _rel(got, want)
+
+
+def test_a_gradient_for_the_held_experts_weights_is_refused():
+    """The held experts are frozen: their weights get no gradient, and asking
+    for one fails rather than returning zeros."""
+    mcfg = _mcfg(2)
+    w, x = _layer(tiny_config(), 2)
+    with pytest.raises(NotImplementedError, match="frozen"):
+        jax.grad(lambda e: jnp.sum(apply_held_moe(x, dict(w, e_up=e), mcfg)[0]))(w["e_up"])
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -266,19 +415,51 @@ def test_chunked_init_programs_match_the_all_clients_ones(world):
     np.testing.assert_array_equal(full.gal_layers, chunked.gal_layers)
 
 
+def _held_loads(m, prec, params, lora, tokens):
+    """The reference's load on each held expert (held,) over ``tokens``
+    (B, T): the tokens whose top-k picks it, in the tiny model's one expert
+    layer (after its one dense layer)."""
+    assert m.n_dense == 1 and len(jax.tree.leaves(params["layers"])[0]) == 1
+    lo = lora["layers"]
+    w0 = ref._f32(jax.tree.map(lambda x: x[0], params["dense_layers"]))
+    w1 = ref._f32(jax.tree.map(lambda x: x[0], params["layers"]))
+    h = params["embed"][tokens].astype(jnp.float32)
+    h = m.dense(prec, m.attention(prec, h, w0, jax.tree.map(lambda x: x[0], lo)), w0)
+    h = m.attention(prec, h, w1, jax.tree.map(lambda x: x[1], lo))
+    picks, _ = m.route(prec, m.rms(h, w1["mlp_norm_w"]), w1)
+    return np.asarray([int(jnp.sum(picks == e)) for e in range(m.held)])
+
+
 def test_the_held_assignment_counter_matches_the_reference(world):
     """One round at learning rate 0 (the LoRA stays where it is, so the
     reference counts every batch at the initial weights): the round's
     ``fl.moe_held_assignments`` is the reference's count over the valid
-    samples of every batch the cohort trained, and ``fl.moe_expert_rows``
-    every held expert on every token of every lane-step the program ran."""
+    samples of every batch the cohort trained. At every step of the round
+    program each held expert's rung is the smallest of the ladder at 16
+    tokens, 0, 8 or 16 rows (16: the dense branch), that holds the
+    reference's load on it in every lane; ``fl.moe_expert_rows`` is the sum
+    of those rungs over the experts and lanes of every step, and
+    ``fl.moe_dense_fallbacks`` the (lane, expert) pairs at the dense branch."""
     config, _, _, clients = world
     r = _runner(world, "vectorized")
     r.init_phase()
+    plans = []
+    packed = r._packed_round_fn
+
+    def capture():
+        fn = packed()
+
+        def call(*args):
+            plans.append(tuple(np.asarray(a) for a in args[8:12]))  # chosen, the lane plan
+            return fn(*args)
+
+        return call
+
+    r._packed_round_fn = capture
     r.run_round(T_ROUND, lr=0.0)
     held = runtime_metrics.histogram("fl.moe_held_assignments").recent[-1]
     rows = runtime_metrics.histogram("fl.moe_expert_rows").recent[-1]
-    scanned = runtime_metrics.histogram("fl.round_scanned_steps").recent[-1]
+    dense = runtime_metrics.histogram("fl.moe_dense_fallbacks").recent[-1]
     m, prec = ref.Model(config), ref.Precision("f32")
     params, lora = r.params, r.global_lora
     want = 0.0
@@ -287,4 +468,18 @@ def test_the_held_assignment_counter_matches_the_reference(world):
         _, _, n = m.forward(prec, params, lora, jnp.asarray(c["tokens"]))
         want += float(n.sum())  # each sample trains once in a whole-shard round
     assert held == want
-    assert rows == scanned * FL.batch_size * 8 * 2 * 1  # lane-steps x B x T x held x expert layers
+
+    (chosen, lane_client, batch_idx, _), = plans  # one packed round
+    assert (lane_client < len(chosen)).all()  # no lane trains a scratch row (zero LoRA)
+    tokens = np.asarray(r._stack_data["tokens"])  # (clients, batches, B, T)
+    caps = np.asarray((0,) + expert_capacities(tokens[0, 0].size) + (tokens[0, 0].size,))
+    assert caps.tolist() == [0, 8, 16]
+    rungs = []  # (step, held expert)
+    for lc, bidx in zip(lane_client.T, batch_idx.T):  # every step, its lanes
+        loads = np.max([_held_loads(m, prec, params, lora, jnp.asarray(tokens[chosen[c], b]))
+                        for c, b in zip(lc, bidx)], axis=0)  # (held,): each expert's heaviest lane
+        rungs.append(np.searchsorted(caps, loads))  # the smallest rung no less than the load
+    rungs, lanes = np.asarray(rungs), lane_client.shape[0]
+    assert {1, 2} <= set(rungs.ravel())  # a rung of 8 rows and the dense branch both ran
+    assert rows == lanes * caps[rungs].sum()
+    assert dense == lanes * np.sum(rungs == len(caps) - 1)
