@@ -26,7 +26,6 @@ from repro.core.engine import (
     build_round_fn,
     build_difficulty_fn,
     build_fim_warmup_fn,
-    build_sharded_round_fn,
     build_sharded_difficulty_fn,
     build_sharded_fim_warmup_fn,
     client_sharding,

@@ -16,7 +16,7 @@ Client pytrees (LoRA / optimizer state / neuron masks) are stacked along a
 leading client axis; client data lives on one padded ``(C, NB, B, ...)`` grid
 (:func:`repro.data.pipeline.stack_clients`) with validity masks, so padded
 samples and padded curriculum steps are exact no-ops and the vectorized
-engine reproduces the loop engine's numerics. ``donate_argnums`` recycles the
+engine reproduces the loop engine's numerics. Donation recycles the
 stacked buffers, so steady-state rounds allocate nothing persistent.
 
 The initialization phase gets the same treatment: difficulty scoring runs as
@@ -25,24 +25,29 @@ warmup is a scan over warmup epochs of a vmap over clients. Where those
 programs would not fit the device, the runner builds them over chunks of
 clients (``chunk=``, a ``lax.map`` of the vmap) with the same results.
 
-Mesh sharding (``engine="sharded"``): the leading client axis is the data-
-parallel axis of a device mesh. ``build_sharded_round_fn`` jits the *same*
-round body with the stacked client state, data grid, and gathered cohort
-sharded over the mesh's client axes (``launch.mesh.dp_axes``), base params
-and the global GAL LoRA replicated, and the fused weighted FedAvg lowering
-to an all-reduce (psum) over the client axis — the paper's server
-aggregation as a collective. Client counts must be padded to a multiple of
-the mesh's client-group count (``stack_clients(pad_clients_to=...)``); the
-runner also pads the chosen cohort with dedicated padding rows (zero weight,
-zero valid steps) so gather/scatter never write one row twice.
+One builder, :func:`build_round_fn`, jits every variant of the one round
+body (:func:`_round_body`); the runner picks the variant, never a program:
 
-Lane packing (single device only): where the cohort's shards are skewed,
-:func:`build_packed_round_fn` trains the cohort in fewer ``vmap`` lanes than
-clients, each lane running several clients' curriculum steps back to back
-(:func:`repro.core.curriculum.pack_lanes`), so a short client no longer pads
-to the longest. The sharded engine keeps one lane per client (its lanes are
-the mesh's client axis), and the out-of-core cohort program and the async
-engine's per-client program are unchanged.
+- Mesh sharding (``engine="sharded"``, ``mesh=``): the leading client axis
+  is the data-parallel axis of a device mesh. The stacked client state,
+  data grid, and gathered cohort are sharded over the mesh's client axes
+  (``launch.mesh.dp_axes``), base params and the global GAL LoRA
+  replicated, and the fused weighted FedAvg lowers to an all-reduce (psum)
+  over the client axis — the paper's server aggregation as a collective.
+  Client counts must be padded to a multiple of the mesh's client-group
+  count (``stack_clients(pad_clients_to=...)``); the runner also pads the
+  chosen cohort with dedicated padding rows (zero weight, zero valid
+  steps) so gather/scatter never write one row twice.
+- Lane packing (``packed=True``, single device only): where the cohort's
+  shards are skewed, the program trains the cohort in fewer ``vmap`` lanes
+  than clients, each lane running several clients' curriculum steps back
+  to back (:func:`repro.core.curriculum.pack_lanes`), so a short client no
+  longer pads to the longest. The sharded program keeps one lane per
+  client (its lanes are the mesh's client axis).
+- Compressed uploads (``compress=``): the server aggregates fake-quantized
+  GAL deltas, with per-client error-feedback residuals as stacked state.
+- The out-of-core client store runs the unpacked program over a stack of
+  just the cohort, with ``chosen = arange(k)``.
 
 The round program decomposes into two separately-callable pieces shared by
 every engine: :func:`make_client_step` (one masked local step) and
@@ -241,7 +246,7 @@ def _round_body(
     compress: Any = None,
     packed: bool = False,
 ) -> Callable:
-    """The round program shared by the single-device and sharded engines.
+    """The round program of every stacked engine and client store.
 
     ``shard`` constrains gathered per-cohort trees (leading k axis) onto the
     mesh's client axes; identity on one device. ``hoist_client_data`` gathers
@@ -263,7 +268,7 @@ def _round_body(
     ``packed`` (single device only) trains the cohort in the lanes of a
     :func:`repro.core.curriculum.pack_lanes` plan: the program takes
     ``lane_client`` (L, S) after ``chosen``, ``batch_idx``/``step_valid`` are
-    (L, S), and the losses come back (S, L). See :func:`build_packed_round_fn`.
+    (L, S), and the losses come back (S, L). See :func:`build_round_fn`.
 
     With a loss that keeps counts (``loss_fn.masked_stats``), ``losses`` is
     the pair ``(losses, counts)``, each count shaped as the losses.
@@ -454,9 +459,16 @@ def _round_body(
 
 
 def build_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool
+    loss_fn: Callable,
+    opt_update: Callable,
+    *,
+    use_neuron_mask: bool,
+    compress: Any = None,
+    packed: bool = False,
+    mesh: Any = None,
 ) -> Callable:
-    """Jitted full-round program.
+    """The jitted round program: one builder for every variant of
+    :func:`_round_body`.
 
     Signature (leading client axis C on stacked trees, k chosen clients,
     S padded steps, NB padded batches of size B):
@@ -466,287 +478,74 @@ def build_round_fn(
     -> (new_global_lora, new_stacked_lora, new_stacked_opt, losses (S, k))``
 
     ``neuron_mask`` is ignored (pass anything hashable-shaped, e.g. the
-    stacked LoRA) when ``use_neuron_mask`` is False.
+    stacked LoRA) when ``use_neuron_mask`` is False. A caller holding only
+    the cohort (the out-of-core store) stacks it to a leading k axis and
+    passes ``chosen = arange(k)``: the gather and scatter are the identity.
+
+    ``compress`` (see :func:`_round_body`) adds two trailing arguments
+    ``(stacked_residual, comp_mask)`` — ``jnp.zeros(())`` placeholders where
+    ``error_feedback``/``has_comp_mask`` are off — and a fifth output, the
+    updated stacked error-feedback residuals.
+
+    ``packed`` trains the cohort in ``L < k`` lanes (``packed_round_fn``):
+    ``lane_client``, ``batch_idx`` and ``step_valid`` (L, S) from
+    :func:`repro.core.curriculum.pack_lanes` follow ``chosen``, and the
+    losses come back (S, L). ``gather``, ``merge_in``, ``fedavg`` and
+    ``scatter`` run over the cohort as unpacked; the ``client_train`` scan
+    carries the cohort's LoRA and optimizer rows (plus L scratch rows) and
+    at each step trains the L rows the lanes name. Every client trains the
+    same batches in the same order from the same merged start, its Adam
+    counter in its own row: only the schedule of lane-steps changes, so a
+    lane no longer pads a short client to the longest one.
+
+    ``mesh`` shards the program: the stacked client state, the data grid
+    and the gathered cohort carry their leading client axis on the mesh's
+    dp axes (the data grid gathered once per round); params, the global
+    LoRA, the GAL mask and the step plan are replicated, and the fused
+    FedAvg lowers to an all-reduce. The stack's client count C and the
+    padded cohort k must be multiples of
+    ``launch.mesh.num_client_groups(mesh)`` (the runner pads both). It
+    keeps one lane per client, which the mesh shards, so ``packed`` with a
+    mesh raises.
+
+    The global LoRA and the stacked client state (LoRA, optimizer, and the
+    residuals under ``compress``) are donated.
     """
-    body = _round_body(loss_fn, opt_update, use_neuron_mask=use_neuron_mask)
-    return jax.jit(body, donate_argnums=(1, 2, 3))
-
-
-def build_compressed_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool, compress
-) -> Callable:
-    """The round program of :func:`build_round_fn` with the compressed-upload
-    aggregation (see :func:`_round_body`): two extra trailing arguments
-    ``(stacked_residual, comp_mask)`` — pass ``jnp.zeros(())`` placeholders
-    when ``error_feedback``/``has_comp_mask`` are off — and a fifth output,
-    the updated stacked error-feedback residuals. The residual state is
-    donated like the other stacked client state."""
-    body = _round_body(
-        loss_fn, opt_update, use_neuron_mask=use_neuron_mask, compress=compress
-    )
-    return jax.jit(body, donate_argnums=(1, 2, 3, 13))
-
-
-def build_packed_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool, compress=None
-) -> Callable:
-    """The round program of :func:`build_round_fn` (or, with ``compress``,
-    :func:`build_compressed_round_fn`) training the cohort in ``L < k`` lanes.
-
-    ``round_fn(params, global_lora, stacked_lora, stacked_opt, neuron_mask,
-    gal_mask, data, sample_valid, chosen, lane_client, batch_idx, step_valid,
-    weights, lr[, stacked_residual, comp_mask]) -> (new_global_lora,
-    new_stacked_lora, new_stacked_opt, losses (S, L)[, new_residual])``, with
-    ``lane_client``/``batch_idx``/``step_valid`` (L, S) from
-    :func:`repro.core.curriculum.pack_lanes`. ``gather``, ``merge_in``,
-    ``fedavg`` and ``scatter`` run over the cohort as in the unpacked
-    program; the ``client_train`` scan carries the cohort's LoRA and
-    optimizer rows (plus L scratch rows) and at each step trains the L rows
-    the lanes name, with the same client step under ``vmap``. Every client
-    trains the same batches in the same order from the same merged start,
-    its Adam counter in its own row: only the schedule of lane-steps
-    changes, so a lane no longer pads a short client to the longest one.
-    Single device only: the sharded engine keeps one lane per client, which
-    its mesh shards.
-    """
+    if packed and mesh is not None:
+        raise ValueError("lane packing is single-device: a mesh shards one lane per client")
+    donate = ("global_lora", "stacked_lora", "stacked_opt")
+    if compress is not None:
+        donate += ("stacked_residual",)
+    body_kw, jit_kw = {}, {}
+    if mesh is not None:
+        client = client_sharding(mesh)
+        repl = replicated_sharding(mesh)
+        body_kw = dict(
+            shard=lambda t: jax.lax.with_sharding_constraint(t, client),
+            hoist_client_data=True,
+        )
+        in_shardings = (
+            repl,  # params
+            repl,  # global_lora
+            client,  # stacked_lora
+            client,  # stacked_opt
+            client if use_neuron_mask else repl,  # neuron_mask
+            repl,  # gal_mask
+            client,  # data
+            client,  # sample_valid
+        ) + (repl,) * 5  # chosen, batch_idx, step_valid, weights, lr
+        out_shardings = (repl, client, client, repl)
+        if compress is not None:
+            # scalar placeholders, where their knob is off, replicate
+            res = client if compress["error_feedback"] else repl
+            in_shardings += (res, client if compress["has_comp_mask"] else repl)
+            out_shardings += (res,)
+        jit_kw = dict(in_shardings=in_shardings, out_shardings=out_shardings)
     body = _round_body(
         loss_fn, opt_update, use_neuron_mask=use_neuron_mask, compress=compress,
-        packed=True,
+        packed=packed, **body_kw,
     )
-    donate = (1, 2, 3) if compress is None else (1, 2, 3, 14)
-    return jax.jit(body, donate_argnums=donate)
-
-
-def _cohort_round_body(
-    loss_fn: Callable,
-    opt_update: Callable,
-    *,
-    use_neuron_mask: bool,
-    compress: Any = None,
-) -> Callable:
-    """:func:`_round_body` for a *materialized cohort* — no population stack.
-
-    The vectorized engine owns a (C, ...) stack for the whole population and
-    gathers/scatters the round's k rows in-program. With an out-of-core
-    client store the population never fits on device, so the host fetches
-    just the cohort, stacks it to a leading k axis, and this body trains it
-    directly: identical line-15 merge, step scan, and fused server
-    aggregation, minus the gather/scatter bookends. Data arrives as the
-    cohort's own ``(k, NB, B, ...)`` grid (``stack_cohort``), already
-    bucketed so every round with the same (k, NB, S) shape reuses one
-    compiled program. It keeps one lane per client: lane packing
-    (:func:`build_packed_round_fn`) is the in-memory stack's alone.
-    """
-
-    def round_fn(
-        params,
-        global_lora,
-        cohort_lora,
-        cohort_opt,
-        neuron_mask,
-        gal_mask,
-        data: Dict[str, Any],
-        sample_valid,
-        batch_idx,
-        step_valid,
-        weights,
-        lr,
-        cohort_residual=None,
-        comp_mask=None,
-    ):
-        # line 15: overwrite the GAL part of each client's LoRA with the
-        # global copy (dtype-preserving, gal_mask broadcast over k); the
-        # named scopes are _round_body's, less gather and scatter
-        with jax.named_scope("merge_in"):
-            cl_lora = jax.tree.map(
-                lambda g, l, m: (m * g + (1.0 - m) * l).astype(l.dtype),
-                global_lora, cohort_lora, gal_mask,
-            )
-        cl_opt = cohort_opt
-        cl_mask = neuron_mask if use_neuron_mask else None
-
-        client_step = make_client_step(loss_fn, opt_update)
-
-        def one_step(lo, op, mk, batch, sv, act):
-            return client_step(params, lo, op, mk, batch, sv, lr, act)
-
-        def step(carry, xs):
-            lora_c, opt_c = carry
-            bidx, active = xs  # (k,), (k,)
-            batch = {kk: jax.vmap(lambda d, j: d[j])(v, bidx) for kk, v in data.items()}
-            sv = jax.vmap(lambda d, j: d[j])(sample_valid, bidx)
-            if use_neuron_mask:
-                loss, lora_c, opt_c = jax.vmap(one_step)(
-                    lora_c, opt_c, cl_mask, batch, sv, active
-                )
-            else:
-                loss, lora_c, opt_c = jax.vmap(
-                    lambda lo, op, b, m, a: one_step(lo, op, None, b, m, a)
-                )(lora_c, opt_c, batch, sv, active)
-            return (lora_c, opt_c), loss
-
-        with jax.named_scope("client_train"):
-            (cl_lora, cl_opt), losses = jax.lax.scan(
-                step, (cl_lora, cl_opt), (batch_idx.T, step_valid.T)
-            )
-
-        if compress is None:
-            with jax.named_scope("fedavg"):
-                new_global = gal_weighted_merge(global_lora, gal_mask, cl_lora, weights)
-            return new_global, cl_lora, cl_opt, losses
-
-        # compressed upload: same fake-quantize/top-k round trip as the
-        # stacked engine, on the cohort's own residual rows
-        from repro.kernels import ops as _kops
-
-        ef = compress["error_feedback"]
-
-        def one(d, r, cm):
-            return _kops.fake_compress(
-                d, r, gal_mask if cm is None else cm,
-                qmax=compress["qmax"],
-                topk_ratio=compress["topk_ratio"],
-                use_thresh=compress["use_thresh"],
-            )
-
-        with jax.named_scope("fedavg"):
-            delta = jax.tree.map(
-                lambda l, g, m: (l - g) * m, cl_lora, global_lora, gal_mask
-            )
-            y, new_res = jax.vmap(
-                one,
-                in_axes=(
-                    0,
-                    0 if ef else None,
-                    0 if compress["has_comp_mask"] else None,
-                ),
-            )(delta, cohort_residual if ef else None, comp_mask if compress["has_comp_mask"] else None)
-            new_global = gal_delta_merge(global_lora, gal_mask, y, weights)
-        return (
-            new_global,
-            cl_lora,
-            cl_opt,
-            losses,
-            new_res if ef else cohort_residual,
-        )
-
-    return round_fn
-
-
-def build_cohort_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool
-) -> Callable:
-    """Jitted cohort round program for the out-of-core client store.
-
-    ``round_fn(params, global_lora, cohort_lora, cohort_opt, neuron_mask,
-    gal_mask, data, sample_valid, batch_idx, step_valid, weights, lr) ->
-    (new_global_lora, new_cohort_lora, new_cohort_opt, losses (S, k))`` —
-    every cohort-stacked argument carries a leading k axis over the round's
-    clients; the host unstacks the outputs back into the store. The cohort
-    state is donated (it was stacked fresh for this round and the updated
-    copy replaces it).
-    """
-    body = _cohort_round_body(loss_fn, opt_update, use_neuron_mask=use_neuron_mask)
-    return jax.jit(body, donate_argnums=(1, 2, 3))
-
-
-def build_cohort_compressed_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool, compress
-) -> Callable:
-    """:func:`build_cohort_round_fn` with the compressed-upload aggregation:
-    two extra trailing arguments ``(cohort_residual, comp_mask)`` — scalar
-    placeholders when their knob is off — and a fifth output, the cohort's
-    updated error-feedback residual rows."""
-    body = _cohort_round_body(
-        loss_fn, opt_update, use_neuron_mask=use_neuron_mask, compress=compress
-    )
-    return jax.jit(body, donate_argnums=(1, 2, 3, 12))
-
-
-def build_sharded_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool, mesh
-) -> Callable:
-    """The round program of :func:`build_round_fn`, sharded over ``mesh``.
-
-    The stacked client state, padded data grid, and the gathered cohort carry
-    their leading client axis on the mesh's dp axes; params / global LoRA /
-    the GAL mask / the step plan are replicated. Requires the stack's client
-    count C and the padded cohort size k to be multiples of
-    ``launch.mesh.num_client_groups(mesh)`` (the runner pads both). It keeps
-    one lane per client, sharded over the mesh: the runner packs lanes
-    (:func:`build_packed_round_fn`) on one device only.
-    """
-    client = client_sharding(mesh)
-    repl = replicated_sharding(mesh)
-    body = _round_body(
-        loss_fn,
-        opt_update,
-        use_neuron_mask=use_neuron_mask,
-        shard=lambda t: jax.lax.with_sharding_constraint(t, client),
-        hoist_client_data=True,
-    )
-    return jax.jit(
-        body,
-        in_shardings=(
-            repl,  # params
-            repl,  # global_lora
-            client,  # stacked_lora
-            client,  # stacked_opt
-            client if use_neuron_mask else repl,  # neuron_mask
-            repl,  # gal_mask
-            client,  # data
-            client,  # sample_valid
-            repl,  # chosen
-            repl,  # batch_idx
-            repl,  # step_valid
-            repl,  # weights
-            repl,  # lr
-        ),
-        out_shardings=(repl, client, client, repl),
-        donate_argnums=(1, 2, 3),
-    )
-
-
-def build_sharded_compressed_round_fn(
-    loss_fn: Callable, opt_update: Callable, *, use_neuron_mask: bool,
-    compress, mesh
-) -> Callable:
-    """:func:`build_compressed_round_fn` sharded over ``mesh`` — the stacked
-    residual state and the optional per-client top-k count mask ride the
-    client axis (scalar placeholders, when their knob is off, replicate)."""
-    client = client_sharding(mesh)
-    repl = replicated_sharding(mesh)
-    body = _round_body(
-        loss_fn,
-        opt_update,
-        use_neuron_mask=use_neuron_mask,
-        shard=lambda t: jax.lax.with_sharding_constraint(t, client),
-        hoist_client_data=True,
-        compress=compress,
-    )
-    res_shd = client if compress["error_feedback"] else repl
-    return jax.jit(
-        body,
-        in_shardings=(
-            repl,  # params
-            repl,  # global_lora
-            client,  # stacked_lora
-            client,  # stacked_opt
-            client if use_neuron_mask else repl,  # neuron_mask
-            repl,  # gal_mask
-            client,  # data
-            client,  # sample_valid
-            repl,  # chosen
-            repl,  # batch_idx
-            repl,  # step_valid
-            repl,  # weights
-            repl,  # lr
-            res_shd,  # stacked_residual
-            client if compress["has_comp_mask"] else repl,  # comp_mask
-        ),
-        out_shardings=(repl, client, client, repl, res_shd),
-        donate_argnums=(1, 2, 3, 13),
-    )
+    return jax.jit(body, donate_argnames=donate, **jit_kw)
 
 
 def _client_train_body(

@@ -471,9 +471,11 @@ class FibecFed:
             self._stack_data = {k_: jnp.asarray(v) for k_, v in stack.data.items()}
             self._sample_valid = jnp.asarray(stack.sample_valid)
 
+        # the round program's cohort rows (the sharded engine pads them)
+        self._cohort_pad = min(fl.devices_per_round, len(self.clients))
         if vectorized and not oocore:
-            C = len(self.clients)
-            k = min(fl.devices_per_round, C)
+            C, k = len(self.clients), self._cohort_pad
+            C_stack = C
             if self.mesh is not None:
                 # pad the stack to a multiple of the mesh's client groups,
                 # with enough inert rows to also pad each round's cohort
@@ -482,9 +484,6 @@ class FibecFed:
                 G = num_client_groups(self.mesh)
                 self._cohort_pad = -(-k // G) * G
                 C_stack = -(-(C + self._cohort_pad - k) // G) * G
-            else:
-                self._cohort_pad = k
-                C_stack = C
             stack = stack_clients(client_data, fl.batch_size, pad_clients_to=C_stack)
             self._stack_data = {k_: jnp.asarray(v) for k_, v in stack.data.items()}
             self._sample_valid = jnp.asarray(stack.sample_valid)
@@ -723,8 +722,8 @@ class FibecFed:
     def _compress_static(self) -> Optional[Dict[str, Any]]:
         """Static compression spec baked into the round program (trace-time
         constants: quantizer width, top-k fraction, which optional inputs
-        exist). ``None`` when compression is off — the untouched builders
-        produce bit-identical programs to the uncompressed stack."""
+        exist). ``None`` when compression is off: the round program then takes
+        and returns no compression state."""
         if self.compression is None:
             return None
         c = self.compression
@@ -736,50 +735,27 @@ class FibecFed:
             "has_comp_mask": bool(c.use_thresh and self.client_ranks is not None),
         }
 
-    def _round_fn(self):
-        loss_fn, opt_update, mesh = self.loss_fn, self.opt_update, self.mesh
-        use_mask = self._stacked_mask is not None
-        comp = self._compress_static()
-        if comp is not None:
-            ckey = tuple(sorted(comp.items()))
-            if mesh is not None:
-                return _memo(
-                    ("round_c", loss_fn, self._opt_key, use_mask, ckey, mesh),
-                    lambda: eng.build_sharded_compressed_round_fn(
-                        loss_fn, opt_update, use_neuron_mask=use_mask,
-                        compress=comp, mesh=mesh,
-                    ),
-                )
-            return _memo(
-                ("round_c", loss_fn, self._opt_key, use_mask, ckey),
-                lambda: eng.build_compressed_round_fn(
-                    loss_fn, opt_update, use_neuron_mask=use_mask, compress=comp
-                ),
-            )
-        if mesh is not None:
-            return _memo(
-                ("round", loss_fn, self._opt_key, use_mask, mesh),
-                lambda: eng.build_sharded_round_fn(
-                    loss_fn, opt_update, use_neuron_mask=use_mask, mesh=mesh
-                ),
-            )
-        return _memo(
-            ("round", loss_fn, self._opt_key, use_mask),
-            lambda: eng.build_round_fn(loss_fn, opt_update, use_neuron_mask=use_mask),
-        )
+    def _use_mask(self) -> bool:
+        """Whether local steps are masked: the programs' ``use_neuron_mask``.
+        ``init_phase`` gives every client a mask where ``sparse_update``
+        builds FIM neuron masks or ``client_ranks`` folds in rank
+        keep-masks (which gate updates even with ``sparse_update`` off)."""
+        return self.sparse_update or self.client_ranks is not None
 
-    def _packed_round_fn(self):
-        """The single-device round program over a :func:`curr.pack_lanes`
-        plan (:func:`eng.build_packed_round_fn`), with or without
-        compression; :meth:`_round_fn` stays the unpacked program."""
-        loss_fn, opt_update = self.loss_fn, self.opt_update
-        use_mask = self._stacked_mask is not None
+    def _round_fn(self, packed: bool = False):
+        """The stacked engines' round program (:func:`eng.build_round_fn`):
+        sharded where the runner has a mesh, compressed where it compresses,
+        over a :func:`curr.pack_lanes` plan where ``packed``. With no
+        argument, the unpacked program."""
+        loss_fn, opt_update, mesh = self.loss_fn, self.opt_update, self.mesh
+        use_mask = self._use_mask()
         comp = self._compress_static()
         ckey = None if comp is None else tuple(sorted(comp.items()))
         return _memo(
-            ("round_packed", loss_fn, self._opt_key, use_mask, ckey),
-            lambda: eng.build_packed_round_fn(
-                loss_fn, opt_update, use_neuron_mask=use_mask, compress=comp
+            ("round", loss_fn, self._opt_key, use_mask, ckey, mesh, packed),
+            lambda: eng.build_round_fn(
+                loss_fn, opt_update, use_neuron_mask=use_mask, compress=comp,
+                packed=packed, mesh=mesh,
             ),
         )
 
@@ -807,29 +783,6 @@ class FibecFed:
             return None
         return curr.pack_lanes([bi[sv > 0] for bi, sv in zip(batch_idx, step_valid)], S, L)
 
-    def _cohort_round_fn(self, use_mask: bool):
-        """Round program over a *materialized cohort* (out-of-core store):
-        the stacked engines' round body minus the population gather/scatter
-        bookends. Programs are keyed on the cohort-stack shape by ``jit``;
-        ``stack_cohort``'s pow2 batch bucketing keeps the distinct shapes
-        (and therefore compiles) logarithmic in the population's spread."""
-        loss_fn, opt_update = self.loss_fn, self.opt_update
-        comp = self._compress_static()
-        if comp is not None:
-            ckey = tuple(sorted(comp.items()))
-            return _memo(
-                ("cohort_round_c", loss_fn, self._opt_key, use_mask, ckey),
-                lambda: eng.build_cohort_compressed_round_fn(
-                    loss_fn, opt_update, use_neuron_mask=use_mask, compress=comp
-                ),
-            )
-        return _memo(
-            ("cohort_round", loss_fn, self._opt_key, use_mask),
-            lambda: eng.build_cohort_round_fn(
-                loss_fn, opt_update, use_neuron_mask=use_mask
-            ),
-        )
-
     # async-engine programs ----------------------------------------------
 
     def _client_train_fn(self):
@@ -837,9 +790,7 @@ class FibecFed:
         client's curriculum steps with no vmap barrier. Memoized like every
         other program so ``clear_compile_caches`` covers it."""
         loss_fn, opt_update = self.loss_fn, self.opt_update
-        # presence-based: rank keep-masks fold into neuron_mask even with
-        # sparse_update off, and they must gate local updates identically
-        use_mask = self.clients[0].neuron_mask is not None
+        use_mask = self._use_mask()
         return _memo(
             ("client_train", loss_fn, self._opt_key, use_mask),
             lambda: eng.build_client_train_fn(
@@ -1321,13 +1272,13 @@ class FibecFed:
             m.gauge("jit.client_train_traces").set(
                 eng.trace_cache_size(self._client_train_fn())
             )
-        elif self._stacked_engine and not self._oocore:
+        elif self._stacked_engine:
             m.gauge("jit.round_fn_traces").set(
                 eng.trace_cache_size(self._round_fn())
             )
-            if self.mesh is None:
+            if self.mesh is None and not self._oocore:
                 m.gauge("jit.packed_round_fn_traces").set(
-                    eng.trace_cache_size(self._packed_round_fn())
+                    eng.trace_cache_size(self._round_fn(packed=True))
                 )
         return stats
 
@@ -1335,8 +1286,6 @@ class FibecFed:
         if self._async:
             return self._run_round_async(t, lr)
         if self._stacked_engine:
-            if self._oocore:
-                return self._run_round_cohort(t, lr)
             return self._run_round_vectorized(t, lr)
         return self._run_round_loop(t, lr)
 
@@ -1408,85 +1357,136 @@ class FibecFed:
     def _run_round_vectorized(
         self, t: int, lr: Optional[float] = None
     ) -> Dict[str, float]:
+        """One synchronous round of the stacked engines, on either store, in
+        five phases: ``plan``, ``put``, ``dispatch``, ``wait`` and
+        ``account`` (:func:`_round_phase`).
+
+        In memory the round program gathers the cohort's rows from the
+        population stack and scatters them back. Out of core only the
+        cohort is resident: it is pinned from the plan's first read of it
+        until it is written back; ``put`` fetches it and stacks it to a
+        leading k axis with its streamed data grid (``stack_cohort``); the
+        same program trains that stack with ``chosen = arange(k)`` (the
+        identity gather and scatter); and after ``wait`` the rows are
+        unstacked into the store. Peak memory then scales with the cohort
+        and the store's hot set, never the population.
+        """
         fl, tel = self.fl, self.tel
         lr = fl.learning_rate if lr is None else lr
-        with _round_phase(tel, "plan"):
-            k = min(fl.devices_per_round, len(self.clients))
-            chosen = self.rng.choice(len(self.clients), k, replace=False)
-            orders = [self.clients[ci].order for ci in chosen]
-            batch_idx, step_valid = curr.step_plan(
-                self.schedule, t, orders, fl.local_epochs
-            )
-            w = np.asarray([self.clients[ci].n for ci in chosen], np.float64)
-            w = (w / w.sum()).astype(np.float32)
-            # one device: pack the cohort's ragged step runs into fewer
-            # lanes where the population's skew allows (the sharded engine's
-            # lanes are the mesh's client axis)
-            lanes = self._lane_plan(batch_idx, step_valid) if self.mesh is None else None
+        comp = self._compress_static()
+        ef = comp is not None and comp["error_feedback"]
+        pinned: List[int] = []
+        try:
+            with _round_phase(tel, "plan"):
+                k = min(fl.devices_per_round, len(self.clients))
+                chosen = self.rng.choice(len(self.clients), k, replace=False)
+                if self._oocore:
+                    pinned = [int(ci) for ci in chosen]
+                    for ci in pinned:
+                        self.store.pin(ci)
+                states = [self.clients[ci] for ci in chosen]
+                orders = [s.order for s in states]
+                batch_idx, step_valid = curr.step_plan(
+                    self.schedule, t, orders, fl.local_epochs
+                )
+                w = np.asarray([s.n for s in states], np.float64)
+                w = (w / w.sum()).astype(np.float32)
+                # one device, in memory: pack the cohort's ragged step runs
+                # into fewer lanes where the population's skew allows (the
+                # sharded engine's lanes are the mesh's client axis)
+                lanes = (
+                    self._lane_plan(batch_idx, step_valid)
+                    if self.mesh is None and not self._oocore
+                    else None
+                )
 
-            if self._cohort_pad > k:
-                # sharded engine: pad the cohort onto the stack's inert padding
-                # rows (distinct indices keep the scatter free of duplicate
-                # writes; zero weight and zero valid steps make them no-ops)
-                pad_n = self._cohort_pad - k
-                pad_rows = np.arange(len(self.clients), len(self.clients) + pad_n)
-                chosen = np.concatenate([chosen, pad_rows])
-                batch_idx = np.pad(batch_idx, ((0, pad_n), (0, 0)))
-                step_valid = np.pad(step_valid, ((0, pad_n), (0, 0)))
-                w = np.pad(w, (0, pad_n))
+                if self._cohort_pad > k:
+                    # sharded engine: pad the cohort onto the stack's inert
+                    # padding rows (distinct indices keep the scatter free of
+                    # duplicate writes; zero weight and zero valid steps make
+                    # them no-ops)
+                    pad_n = self._cohort_pad - k
+                    pad_rows = np.arange(len(self.clients), len(self.clients) + pad_n)
+                    chosen = np.concatenate([chosen, pad_rows])
+                    batch_idx = np.pad(batch_idx, ((0, pad_n), (0, 0)))
+                    step_valid = np.pad(step_valid, ((0, pad_n), (0, 0)))
+                    w = np.pad(w, (0, pad_n))
 
-        with _round_phase(tel, "put"):
-            mask_arg = (
-                self._stacked_mask if self._stacked_mask is not None else jnp.zeros(())
-            )
-            args = (
-                self.params,
-                self.global_lora,
-                self._stacked_lora,
-                self._stacked_opt,
-                mask_arg,
-                self._gal_mask_tree,
-                self._stack_data,
-                self._sample_valid,
-                jnp.asarray(chosen, jnp.int32),
-                *map(jnp.asarray, (batch_idx, step_valid) if lanes is None else lanes),
-                jnp.asarray(w),
-                jnp.float32(lr),
-            )
-            if self.compression is not None:
-                res_arg = (
-                    self._stacked_residual
-                    if self.compression.error_feedback
-                    else jnp.zeros(())
-                )
-                cm_arg = (
-                    self._stacked_comp_mask
-                    if self._stacked_comp_mask is not None
-                    else jnp.zeros(())
-                )
-                args += (res_arg, cm_arg)
-        with _round_phase(tel, "dispatch"):
-            round_fn = self._round_fn() if lanes is None else self._packed_round_fn()
-            if self.compression is None:
-                self.global_lora, self._stacked_lora, self._stacked_opt, losses = (
-                    round_fn(*args)
-                )
-            else:
-                (
+            with _round_phase(tel, "put"):
+                if self._oocore:
+                    # the data grid is streamed per round: bucket the batch
+                    # axis so rounds of one (k, NB, S) shape share a program
+                    nb = max(len(s.batches) for s in states)
+                    grid = stack_cohort(
+                        [self.store.client_data(ci) for ci in pinned],
+                        fl.batch_size,
+                        pad_batches_to=bucket_size(nb),
+                    )
+                    data = {k_: jnp.asarray(v) for k_, v in grid.data.items()}
+                    sample_valid = jnp.asarray(grid.sample_valid)
+
+                    def stack(trees):
+                        return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+
+                    rows = np.arange(k)
+                    lora = stack([s.lora for s in states])
+                    opt = stack([s.opt_state for s in states])
+                    mask = stack([s.neuron_mask for s in states]) if self._use_mask() else None
+                    residual = stack([s.ef_residual for s in states]) if ef else None
+                    comp_mask = (
+                        stack([self._comp_mask(ci) for ci in pinned])
+                        if comp is not None and comp["has_comp_mask"]
+                        else None
+                    )
+                else:
+                    rows, data, sample_valid = chosen, self._stack_data, self._sample_valid
+                    lora, opt, mask = self._stacked_lora, self._stacked_opt, self._stacked_mask
+                    residual = self._stacked_residual if ef else None
+                    comp_mask = self._stacked_comp_mask
+
+                def arg(x):
+                    return jnp.zeros(()) if x is None else x
+
+                args = (
+                    self.params,
                     self.global_lora,
-                    self._stacked_lora,
-                    self._stacked_opt,
-                    losses,
-                    new_res,
-                ) = round_fn(*args)
-                if self.compression.error_feedback:
-                    self._stacked_residual = new_res
+                    lora,
+                    opt,
+                    arg(mask),
+                    self._gal_mask_tree,
+                    data,
+                    sample_valid,
+                    jnp.asarray(rows, jnp.int32),
+                    *map(jnp.asarray, (batch_idx, step_valid) if lanes is None else lanes),
+                    jnp.asarray(w),
+                    jnp.float32(lr),
+                )
+                if comp is not None:
+                    args += (arg(residual), arg(comp_mask))
+            with _round_phase(tel, "dispatch"):
+                round_fn = self._round_fn(packed=lanes is not None)
+                self.global_lora, lora, opt, losses, *new_res = round_fn(*args)
+                if not self._oocore:
+                    self._stacked_lora, self._stacked_opt = lora, opt
+                    if ef:
+                        self._stacked_residual = new_res[0]
 
-        with _round_phase(tel, "wait"):
-            # a held-expert MoE's program returns (losses, counts)
-            losses, counts = losses if isinstance(losses, tuple) else (losses, {})
-            losses = np.asarray(losses)  # (S, lanes)
-            counts = {name: float(np.sum(c)) for name, c in counts.items()}
+            with _round_phase(tel, "wait"):
+                # a held-expert MoE's program returns (losses, counts)
+                losses, counts = losses if isinstance(losses, tuple) else (losses, {})
+                losses = np.asarray(losses)  # (S, lanes)
+                counts = {name: float(np.sum(c)) for name, c in counts.items()}
+            for i, (ci, s) in enumerate(zip(pinned, states)):
+                # out of core: row i of the outputs is client ci's new state
+                s.lora = jax.tree.map(lambda x, i=i: x[i], lora)
+                s.opt_state = jax.tree.map(lambda x, i=i: x[i], opt)
+                if ef:
+                    s.ef_residual = jax.tree.map(lambda x, i=i: x[i], new_res[0])
+                self.store.put(ci, s)
+        finally:
+            for ci in pinned:
+                self.store.unpin(ci)
+
         with _round_phase(tel, "account"):
             valid = (step_valid if lanes is None else lanes[2]).T
             mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
@@ -1517,126 +1517,6 @@ class FibecFed:
             "comm_bytes": float(self.comm_bytes_per_round[-1]),
             # compiled step-shape of this round (pow2-bucketed): the
             # curriculum-bucketing test asserts few distinct values per ramp
-            "padded_steps": float(batch_idx.shape[1]),
-        }
-
-    def _run_round_cohort(
-        self, t: int, lr: Optional[float] = None
-    ) -> Dict[str, float]:
-        """The vectorized round against an out-of-core client store.
-
-        Same cohort draw, curriculum plan, FedAvg weighting, and comm
-        accounting as ``_run_round_vectorized`` — but only the sampled
-        cohort's states are fetched (pinned against eviction for the round),
-        host-stacked to a leading k axis together with their streamed data
-        grid (``stack_cohort``), trained by the cohort round program, and
-        unstacked back into the store. Peak memory scales with the cohort
-        and the store's hot set, never the population.
-        """
-        fl = self.fl
-        lr = fl.learning_rate if lr is None else lr
-        C = len(self.clients)
-        k = min(fl.devices_per_round, C)
-        chosen = self.rng.choice(C, k, replace=False)
-        cohort = [int(ci) for ci in chosen]
-        for ci in cohort:
-            self.store.pin(ci)
-        try:
-            states = [self.clients[ci] for ci in cohort]
-            orders = [s.order for s in states]
-            batch_idx, step_valid = curr.step_plan(
-                self.schedule, t, orders, fl.local_epochs
-            )
-            w = np.asarray([s.n for s in states], np.float64)
-            w = (w / w.sum()).astype(np.float32)
-
-            # the data grid is streamed per round: bucket the batch axis so
-            # rounds with the same (k, NB, S) shape share a compiled program
-            nb = max(len(s.batches) for s in states)
-            grid = stack_cohort(
-                [self.store.client_data(ci) for ci in cohort],
-                fl.batch_size,
-                pad_batches_to=bucket_size(nb),
-            )
-            data = {k_: jnp.asarray(v) for k_, v in grid.data.items()}
-            sv = jnp.asarray(grid.sample_valid)
-
-            def _stack(trees):
-                return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-            cohort_lora = _stack([s.lora for s in states])
-            cohort_opt = _stack([s.opt_state for s in states])
-            use_mask = states[0].neuron_mask is not None
-            mask_arg = (
-                _stack([s.neuron_mask for s in states])
-                if use_mask
-                else jnp.zeros(())
-            )
-            round_fn = self._cohort_round_fn(use_mask)
-            args = (
-                self.params,
-                self.global_lora,
-                cohort_lora,
-                cohort_opt,
-                mask_arg,
-                self._gal_mask_tree,
-                data,
-                sv,
-                jnp.asarray(batch_idx),
-                jnp.asarray(step_valid),
-                jnp.asarray(w),
-                jnp.float32(lr),
-            )
-            new_res = None
-            if self.compression is None:
-                self.global_lora, new_lora, new_opt, losses = round_fn(*args)
-            else:
-                ef = self.compression.error_feedback
-                res_arg = (
-                    _stack([s.ef_residual for s in states]) if ef else jnp.zeros(())
-                )
-                cm_arg = (
-                    _stack([self._comp_mask(ci) for ci in cohort])
-                    if self._compress_static()["has_comp_mask"]
-                    else jnp.zeros(())
-                )
-                self.global_lora, new_lora, new_opt, losses, res_out = round_fn(
-                    *args, res_arg, cm_arg
-                )
-                if ef:
-                    new_res = res_out
-            for i, (ci, s) in enumerate(zip(cohort, states)):
-                s.lora = jax.tree.map(lambda x, i=i: x[i], new_lora)
-                s.opt_state = jax.tree.map(lambda x, i=i: x[i], new_opt)
-                if new_res is not None:
-                    s.ef_residual = jax.tree.map(lambda x, i=i: x[i], new_res)
-                self.store.put(ci, s)
-        finally:
-            for ci in cohort:
-                self.store.unpin(ci)
-
-        losses = np.asarray(losses)  # (S, k)
-        valid = step_valid.T
-        mean_loss = float(np.sum(losses * valid) / max(np.sum(valid), 1.0))
-
-        self.last_round_info = {
-            "chosen": np.asarray(chosen),
-            "client_steps": step_valid.sum(axis=1).astype(np.int64),
-        }
-        total, up = self._gal_bytes(chosen)
-        self.comm_bytes_per_round.append(total)
-        self.comm_upload_bytes_per_round.append(up)
-        return {
-            "loss": mean_loss,
-            "selected_batches": float(
-                np.mean(
-                    [
-                        len(curr.selected_batch_ids(self.schedule, t, o))
-                        for o in orders
-                    ]
-                )
-            ),
-            "comm_bytes": float(self.comm_bytes_per_round[-1]),
             "padded_steps": float(batch_idx.shape[1]),
         }
 

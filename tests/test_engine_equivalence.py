@@ -495,33 +495,52 @@ def test_compression_none_is_exact_noop(world):
     assert r_base.comm_upload_bytes_per_round == r_none.comm_upload_bytes_per_round
 
 
+COMP_KWS = [
+    dict(mode="int8"),
+    dict(mode="topk", topk_ratio=0.25, topk_values="int8"),
+    dict(mode="topk", topk_ratio=0.25, topk_values="float", error_feedback=False),
+]
+
+
 @pytest.mark.parametrize(
-    "comp_kw",
+    "comp_kw,engine",
     [
-        dict(mode="int8"),
-        dict(mode="topk", topk_ratio=0.25, topk_values="int8"),
-        dict(mode="topk", topk_ratio=0.25, topk_values="float", error_feedback=False),
+        # the vectorized cases keep the ids they had before the engine axis
+        pytest.param(kw, engine, id=f"comp_kw{i}" if engine == "vectorized" else f"{engine}-comp_kw{i}")
+        for engine in ("vectorized", "sharded", "out_of_core")
+        for i, kw in enumerate(COMP_KWS)
     ],
 )
-def test_compressed_engines_equivalent(world, comp_kw):
-    """loop (spec: host-side channel sim per client) and vectorized (fused
-    in-program vmap'd kernel) must agree under every compression mode —
-    same global trees, same EF residual evolution, same wire bytes."""
-    from repro.federated import CompressionConfig
+def test_compressed_engines_equivalent(world, comp_kw, engine, tmp_path):
+    """loop (spec: host-side channel sim per client) and each stacked round
+    program (fused in-program vmap'd kernel) must agree under every
+    compression mode — same global trees, same EF residual evolution, same
+    wire bytes. The stacked side is the vectorized engine, the sharded one
+    (on a one-device mesh), or the vectorized engine over an out-of-core
+    store that holds fewer clients than the population (its residuals are
+    client state in the store)."""
+    from repro.federated import CompressionConfig, OutOfCoreStore
 
     model, loss_fn, client_data = world
     comp = CompressionConfig(**comp_kw)
+    stacked = {
+        "vectorized": dict(engine="vectorized"),
+        "sharded": dict(engine="sharded", mesh=make_client_mesh(1)),
+        "out_of_core": dict(
+            engine="vectorized", store=OutOfCoreStore(str(tmp_path), hot_slots=2)
+        ),
+    }[engine]
     runners = {}
-    for engine in ("loop", "vectorized"):
+    for key, kw in (("loop", dict(engine="loop")), ("stacked", stacked)):
         r = make_runner(
             "fibecfed", model, loss_fn, FL, client_data,
-            optimizer="adamw", engine=engine, seed=7, compression=comp,
+            optimizer="adamw", seed=7, compression=comp, **kw,
         )
         r.init_phase()
         for t in range(ROUNDS):
             r.run_round(t)
-        runners[engine] = r
-    r_loop, r_vec = runners["loop"], runners["vectorized"]
+        runners[key] = r
+    r_loop, r_vec = runners["loop"], runners["stacked"]
     frac = 0.02 if comp.use_thresh else 0.0
     _assert_close_trees(r_loop.global_lora, r_vec.global_lora, boundary_frac=frac)
     assert r_loop.comm_bytes_per_round == r_vec.comm_bytes_per_round
@@ -532,11 +551,14 @@ def test_compressed_engines_equivalent(world, comp_kw):
     ):
         assert up < total - up
     if comp.error_feedback:
-        stacked = [
-            jax.tree.map(lambda x, ci=ci: x[ci], r_vec._stacked_residual)
-            for ci in range(FL.num_devices)
-        ]
-        for cl, sr in zip(r_loop.clients, stacked):
+        if engine == "out_of_core":
+            residuals = [c.ef_residual for c in r_vec.clients]
+        else:
+            residuals = [
+                jax.tree.map(lambda x, ci=ci: x[ci], r_vec._stacked_residual)
+                for ci in range(FL.num_devices)
+            ]
+        for cl, sr in zip(r_loop.clients, residuals):
             if cl.ef_residual is not None:
                 _assert_close_trees(cl.ef_residual, sr, boundary_frac=frac)
 

@@ -223,6 +223,20 @@ def test_a_ramp_compiles_one_packed_program_per_bucket(world):
     buckets = {int(r.run_round(t)["padded_steps"]) for t in range(T + 1)}
     assert buckets == {8, 16}
     assert _count("fl.rounds_packed") - packed0 == T + 1
-    traces = eng.trace_cache_size(r._packed_round_fn())
+    traces = eng.trace_cache_size(r._round_fn(packed=True))
     assert traces == len(buckets)
     assert tel.metrics.gauge("jit.packed_round_fn_traces").value == traces
+
+
+def test_a_packed_program_refuses_a_mesh(world):
+    """The sharded program keeps one lane per client (the mesh's client
+    axis), so the one round-program builder takes no packing with a mesh."""
+    from repro.launch.mesh import make_client_mesh
+    from repro.optim import make_optimizer
+
+    _, loss_fn, _ = world
+    _, opt_update = make_optimizer("adamw")
+    with pytest.raises(ValueError, match="single-device"):
+        eng.build_round_fn(
+            loss_fn, opt_update, use_neuron_mask=True, packed=True, mesh=make_client_mesh(1)
+        )

@@ -397,6 +397,25 @@ def test_the_loop_engine_matches_packed_vectorized_rounds(world):
                  r_vec.global_lora, r_loop.global_lora)
 
 
+def test_an_out_of_core_round_keeps_the_expert_counts(world, tmp_path):
+    """The out-of-core store runs the same round program over a stack of
+    the cohort, so its rounds record the held-expert counts too: at learning
+    rate 0, the same (token, held expert) assignments as the in-memory
+    round, whose lane packing changes none of them."""
+    from repro.federated import OutOfCoreStore
+
+    hist = runtime_metrics.histogram("fl.moe_held_assignments")
+    held = {}
+    for key, kw in (("mem", {}), ("ooc", {"store": OutOfCoreStore(str(tmp_path), hot_slots=2)})):
+        r = _runner(world, "vectorized", **kw)
+        r.init_phase()
+        count = hist.count
+        r.run_round(T_ROUND, lr=0.0)
+        assert hist.count == count + 1
+        held[key] = hist.recent[-1]
+    assert held["ooc"] == held["mem"] > 0
+
+
 def test_chunked_init_programs_match_the_all_clients_ones(world):
     full = _runner(world, "vectorized")
     full.init_phase()
@@ -444,10 +463,12 @@ def test_the_held_assignment_counter_matches_the_reference(world):
     r = _runner(world, "vectorized")
     r.init_phase()
     plans = []
-    packed = r._packed_round_fn
+    round_fn = r._round_fn
 
-    def capture():
-        fn = packed()
+    def capture(packed=False):
+        fn = round_fn(packed=packed)
+        if not packed:
+            return fn
 
         def call(*args):
             plans.append(tuple(np.asarray(a) for a in args[8:12]))  # chosen, the lane plan
@@ -455,7 +476,7 @@ def test_the_held_assignment_counter_matches_the_reference(world):
 
         return call
 
-    r._packed_round_fn = capture
+    r._round_fn = capture
     r.run_round(T_ROUND, lr=0.0)
     held = runtime_metrics.histogram("fl.moe_held_assignments").recent[-1]
     rows = runtime_metrics.histogram("fl.moe_expert_rows").recent[-1]

@@ -441,9 +441,8 @@ def test_round_phase_spans_sum_to_at_most_the_round(world):
         assert starts == sorted(starts)
 
 
-def test_round_phase_histograms_count_every_round_with_telemetry_off(world):
+def _assert_phase_histograms_count_every_round(runner):
     hists = [runtime_metrics.histogram(f"fl.round_{p}_s") for p in ROUND_PHASES]
-    runner, _ = _run_fl(world, "vectorized", telemetry=None, rounds=0)
     for t in range(3):
         before = [(h.count, len(h.recent)) for h in hists]
         runner.run_round(t)
@@ -452,6 +451,20 @@ def test_round_phase_histograms_count_every_round_with_telemetry_off(world):
             assert len(h.recent) == min(n + 1, h.RECENT)
             assert h.recent[-1] >= 0.0
     assert runtime_metrics.snapshot()["histograms"]["fl.round_wait_s"]["count"] == hists[3].count
+
+
+def test_round_phase_histograms_count_every_round_with_telemetry_off(world):
+    runner, _ = _run_fl(world, "vectorized", telemetry=None, rounds=0)
+    _assert_phase_histograms_count_every_round(runner)
+
+
+def test_out_of_core_rounds_record_the_phase_histograms(world, tmp_path):
+    """The out-of-core store's rounds run the same five phases."""
+    from repro.federated import OutOfCoreStore
+
+    store = OutOfCoreStore(str(tmp_path), hot_slots=2)
+    runner, _ = _run_fl(world, "vectorized", telemetry=None, rounds=0, store=store)
+    _assert_phase_histograms_count_every_round(runner)
 
 
 def test_histogram_recent_keeps_the_last_observations():
